@@ -100,8 +100,9 @@ def _resume_qk_features(qs, ks, fparams, cfg: fm.FeatureConfig, c_in,
 
     ``valid_mask`` ((B, 1, 1, L, 1) bool, or None for all-valid) marks
     ragged-row padding: masked positions contribute nothing to the
-    stabilizer maxes and get zero k-features, so a padded row's state
-    advances exactly as its unpadded (B=1) counterpart would.
+    stabilizer maxes and get zero q- and k-features, so a padded row's
+    state advances exactly as its unpadded (B=1) counterpart would, and
+    a row with no valid position (its q max is -inf) stays finite.
     Returns (qf, kf, c_new, rescale)."""
     inv_sqrt_m = cfg.num_features ** -0.5
     qraw = _raw_logits(qs, fparams, cfg.kind)
@@ -122,6 +123,7 @@ def _resume_qk_features(qs, ks, fparams, cfg: fm.FeatureConfig, c_in,
     rescale = jnp.exp(c_in - c_new)                    # <= 1
     kf = jnp.exp(kraw - c_new) * inv_sqrt_m
     if valid_mask is not None:
+        qf = jnp.where(valid_mask, qf, 0.0)
         kf = jnp.where(valid_mask, kf, 0.0)
     return qf, kf, c_new, rescale
 
@@ -153,8 +155,8 @@ def rf_attention(q: Array, k: Array, v: Array, fparams: Optional[dict],
         return la.linear_attention_noncausal(qf, kf, vv, eps=cfg.eps)
     if use_kernel:
         return kops.linear_attention_causal(qf, kf, vv, eps=cfg.eps)
-    return la.linear_attention_causal_chunked(qf, kf, vv, chunk=chunk,
-                                              eps=cfg.eps)
+    return la.linear_attention_causal_blockwise(qf, kf, vv, chunk=chunk,
+                                                eps=cfg.eps)
 
 
 class AttnServeState(NamedTuple):

@@ -171,6 +171,49 @@ def linear_attention_causal_chunked(qf: Array, kf: Array, v: Array,
     return out
 
 
+def linear_attention_causal_blockwise(qf: Array, kf: Array, v: Array,
+                                      chunk: int = 256,
+                                      eps: float = 1e-6) -> Array:
+    """:func:`linear_attention_causal_chunked` without its loop, for the
+    training forward.
+
+    Every chunk reads the exclusive prefix sum of the earlier chunks'
+    K'^T V and sum K', so all chunks run at once. The TPU compiler
+    refuses the training step of a sharded model that rematerializes the
+    chunk scan inside the layer scan. Serving keeps the scan, so that a
+    whole-prompt prefill rounds exactly as a resumed one.
+    """
+    f32 = jnp.float32
+    *batch, l, m = qf.shape
+    dv = v.shape[-1]
+    pad = -l % chunk
+    if pad:
+        widths = [(0, 0)] * len(batch) + [(0, pad), (0, 0)]
+        qf, kf, v = (jnp.pad(t, widths) for t in (qf, kf, v))
+    nc = (l + pad) // chunk
+    ax = len(batch)                                   # the chunk axis
+    qc = qf.reshape(*batch, nc, chunk, m).astype(f32)
+    kc = kf.reshape(*batch, nc, chunk, m).astype(f32)
+    vc = v.reshape(*batch, nc, chunk, dv).astype(f32)
+
+    def before(x):                       # sum over the earlier chunks
+        run = jax.lax.slice_in_dim(jnp.cumsum(x, axis=ax), 0, nc - 1,
+                                   axis=ax)
+        return jnp.concatenate(
+            [jnp.zeros_like(jax.lax.slice_in_dim(x, 0, 1, axis=ax)), run],
+            axis=ax)
+
+    s = before(jnp.einsum("...ckm,...ckd->...cmd", kc, vc))
+    z = before(jnp.sum(kc, axis=-2))
+    tri = jnp.tril(jnp.ones((chunk, chunk), dtype=f32))
+    local = jnp.einsum("...cqm,...ckm->...cqk", qc, kc) * tri
+    num = jnp.einsum("...cqm,...cmd->...cqd", qc, s) + jnp.einsum(
+        "...cqk,...ckd->...cqd", local, vc)
+    den = jnp.einsum("...cqm,...cm->...cq", qc, z) + jnp.sum(local, axis=-1)
+    out = (num / (den[..., None] + eps)).reshape(*batch, nc * chunk, dv)
+    return out[..., :l, :].astype(v.dtype)
+
+
 class LinearState(NamedTuple):
     """O(1) decode state for linear attention: S (m x dv) and z (m)."""
     s: Array   # (..., m, dv) float32

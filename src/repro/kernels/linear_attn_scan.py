@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
-
 Array = jax.Array
 
 
@@ -104,7 +102,7 @@ def linear_attention_causal_fwd(qf: Array, kf: Array, v: Array, *,
             pltpu.VMEM((1, m), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qf, kf, v)
     return out[:, :l]
@@ -119,7 +117,7 @@ def _kernel_carry(q_ref, k_ref, v_ref, s0_ref, z0_ref,
     @pl.when(c == 0)
     def _init():
         s_ref[...] = s0_ref[0].astype(jnp.float32)
-        z_ref[...] = z0_ref[...].astype(jnp.float32)
+        z_ref[...] = z0_ref[0].astype(jnp.float32)
 
     q = q_ref[0].astype(jnp.float32)        # (T, m)
     k = k_ref[0].astype(jnp.float32)        # (T, m)
@@ -152,7 +150,7 @@ def _kernel_carry(q_ref, k_ref, v_ref, s0_ref, z0_ref,
     # the state output block is revisited every sequential step; the last
     # chunk's write is what lands in HBM
     so_ref[0] = s_new
-    zo_ref[...] = z_new[None]
+    zo_ref[0] = z_new[None]
 
 
 def linear_attention_causal_carry_fwd(qf: Array, kf: Array, v: Array,
@@ -179,6 +177,9 @@ def linear_attention_causal_carry_fwd(qf: Array, kf: Array, v: Array,
     lp = l + pad
     nc = lp // t
 
+    # z rides as (N, 1, m): Mosaic tiles the last two block dims by
+    # (8, 128) unless they equal the array's, so the blocked N axis
+    # must lead
     grid = (n, nc)
     out, s_f, z_f = pl.pallas_call(
         functools.partial(_kernel_carry, eps=eps),
@@ -188,24 +189,24 @@ def linear_attention_causal_carry_fwd(qf: Array, kf: Array, v: Array,
             pl.BlockSpec((1, t, m), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, t, dv), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, m, dv), lambda b, c: (b, 0, 0)),
-            pl.BlockSpec((1, m), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, m), lambda b, c: (b, 0, 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, t, dv), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, m, dv), lambda b, c: (b, 0, 0)),
-            pl.BlockSpec((1, m), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, m), lambda b, c: (b, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((n, lp, dv), v.dtype),
             jax.ShapeDtypeStruct((n, m, dv), jnp.float32),
-            jax.ShapeDtypeStruct((n, m), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, m), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.VMEM((m, dv), jnp.float32),
             pltpu.VMEM((1, m), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(qf, kf, v, s0, z0)
-    return out[:, :l], s_f, z_f
+    )(qf, kf, v, s0, z0.reshape(n, 1, m))
+    return out[:, :l], s_f, z_f.reshape(n, m)

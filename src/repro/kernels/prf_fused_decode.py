@@ -42,8 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -66,56 +65,59 @@ def _featurize(x2, a, m_mat):
 def _kernel(q_ref, k_ref, v_ref, a_ref, m_ref, c_ref, s_ref, z_ref,
             o_ref, so_ref, zo_ref, co_ref, *, stabilize: bool,
             eps: float):
-    tb, _, hg, d = q_ref.shape
+    tb, _, hg, _ = q_ref.shape
     m = a_ref.shape[-1]
-    dv = v_ref.shape[-1]
     inv_sqrt_m = m ** -0.5
+    f32 = jnp.float32
+    a = a_ref[0].astype(f32)                             # (d, m)
+    m_mat = None if m_ref is None else m_ref[0].astype(f32)
 
-    q = q_ref[...].astype(jnp.float32).reshape(tb * hg, d)
-    k = k_ref[...].astype(jnp.float32).reshape(tb, d)
-    v = v_ref[...].astype(jnp.float32).reshape(tb, dv)
-    a = a_ref[0].astype(jnp.float32)                     # (d, m)
-    m_mat = None if m_ref is None else m_ref[0].astype(jnp.float32)
-    c_old = c_ref[...].astype(jnp.float32)               # (Tb, 1)
-    s = s_ref[...].astype(jnp.float32).reshape(tb * hg, m, dv)
-    z = z_ref[...].astype(jnp.float32).reshape(tb * hg, m)
-
-    qraw = _featurize(q, a, m_mat)                       # (Tb*Hg, m)
-    kraw = _featurize(k, a, m_mat)                       # (Tb, m) — ONCE
+    # the block's features in one matmul each, from its slots' rows
+    q = jnp.concatenate([q_ref[b, 0] for b in range(tb)], axis=0)
+    k = jnp.concatenate([k_ref[b, 0] for b in range(tb)], axis=0)
+    qraw = _featurize(q.astype(f32), a, m_mat)           # (Tb*Hg, m)
+    kraw = _featurize(k.astype(f32), a, m_mat)           # (Tb, m) — ONCE
     #                                                      per KV group
-    if stabilize:
-        # online running-max: fold the new key's max into the carried
-        # stabilizer and rescale the accumulated state ONCE (§3 of
-        # docs/kernels.md); the q shift cancels pointwise so the
-        # current token's own max is enough.
-        qf = jnp.exp(qraw - jnp.max(qraw, axis=-1, keepdims=True)) \
-            * inv_sqrt_m
-        c_new = jnp.maximum(c_old, jnp.max(kraw, axis=-1, keepdims=True))
-        rho = jnp.exp(c_old - c_new)                     # <= 1
-        kf = jnp.exp(kraw - c_new) * inv_sqrt_m
-    else:
-        # unstabilized features carry c == 0 (the init state's -1e30
-        # sentinel only ever zeroes an all-zero fresh state)
-        qf = jnp.exp(qraw) * inv_sqrt_m
-        c_new = jnp.zeros_like(c_old)
-        rho = jnp.exp(c_old)
-        kf = jnp.exp(kraw) * inv_sqrt_m
+    # static unroll over the block's slots: every operand is a 2-D tile
+    # and every contraction a plain 2-D matmul (Mosaic-shaped)
+    for b in range(tb):
+        qraw_b = qraw[b * hg:(b + 1) * hg]               # (Hg, m)
+        kraw_b = kraw[b:b + 1]                           # (1, m)
+        v = v_ref[b, 0].astype(f32)                      # (1, dv)
+        c_old = c_ref[b, 0]                              # (1, 1)
+        if stabilize:
+            # online running-max: fold the new key's max into the
+            # carried stabilizer and rescale the accumulated state ONCE
+            # (§3 of docs/kernels.md); the q shift cancels pointwise so
+            # the current token's own max is enough.
+            qf = jnp.exp(qraw_b - jnp.max(qraw_b, axis=-1, keepdims=True)) \
+                * inv_sqrt_m
+            c_new = jnp.maximum(c_old,
+                                jnp.max(kraw_b, axis=-1, keepdims=True))
+            rho = jnp.exp(c_old - c_new)                 # <= 1
+            kf = jnp.exp(kraw_b - c_new) * inv_sqrt_m
+        else:
+            # unstabilized features carry c == 0 (the init state's -1e30
+            # sentinel only ever zeroes an all-zero fresh state)
+            qf = jnp.exp(qraw_b) * inv_sqrt_m
+            c_new = jnp.zeros_like(c_old)
+            rho = jnp.exp(c_old)
+            kf = jnp.exp(kraw_b) * inv_sqrt_m
 
-    # broadcast per-group kf/v/rho to the Hg query heads of the block
-    rho_h = jnp.broadcast_to(rho[:, None], (tb, hg, 1)).reshape(-1, 1)
-    kf_h = jnp.broadcast_to(kf[:, None, :], (tb, hg, m)).reshape(-1, m)
-    v_h = jnp.broadcast_to(v[:, None, :], (tb, hg, dv)).reshape(-1, dv)
-
-    s_new = s * rho_h[:, :, None] + kf_h[:, :, None] * v_h[:, None, :]
-    z_new = z * rho_h + kf_h
-    num = jnp.sum(qf[:, :, None] * s_new, axis=1)        # (Tb*Hg, dv)
-    den = jnp.sum(qf * z_new, axis=1, keepdims=True)     # (Tb*Hg, 1)
-
-    o_ref[...] = (num / (den + eps)).astype(o_ref.dtype) \
-        .reshape(tb, 1, hg, dv)
-    so_ref[...] = s_new.astype(so_ref.dtype).reshape(s_ref.shape)
-    zo_ref[...] = z_new.astype(zo_ref.dtype).reshape(z_ref.shape)
-    co_ref[...] = c_new.astype(co_ref.dtype)
+        # rank-1 update kf vᵀ, shared by the Hg query heads of the group
+        kv = jax.lax.dot_general(kf, v, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=f32)  # (m, dv)
+        z_new = z_ref[b, 0].astype(f32) * rho + kf       # (Hg, m)
+        den = jnp.sum(qf * z_new, axis=1, keepdims=True)  # (Hg, 1)
+        for h in range(hg):
+            s_new = s_ref[b, 0, h].astype(f32) * rho + kv  # (m, dv)
+            num = jnp.dot(qf[h:h + 1], s_new,
+                          preferred_element_type=f32)    # (1, dv)
+            o_ref[b, 0, h:h + 1] = (num / (den[h:h + 1] + eps)) \
+                .astype(o_ref.dtype)
+            so_ref[b, 0, h] = s_new.astype(so_ref.dtype)
+        zo_ref[b, 0] = z_new.astype(zo_ref.dtype)
+        co_ref[b, 0] = c_new.astype(co_ref.dtype)
 
 
 def _block_divisor(b: int, block_b: int) -> int:
@@ -149,13 +151,16 @@ def prf_fused_decode_fwd(q: Array, k: Array, v: Array, a: Array,
     tb = _block_divisor(b, block_b)
     grid = (b // tb, g)
 
+    # Mosaic tiles the last two block dims by (8, 128) unless they equal
+    # the array's: the per-(slot, group) operands k, v, c get trailing
+    # unit axes (free reshapes) so the blocked B and G axes are leading
     in_specs = [
         pl.BlockSpec((tb, 1, hg, d), lambda i, gi: (i, gi, 0, 0)),
-        pl.BlockSpec((tb, 1, d), lambda i, gi: (i, gi, 0)),
-        pl.BlockSpec((tb, 1, dv), lambda i, gi: (i, gi, 0)),
+        pl.BlockSpec((tb, 1, 1, d), lambda i, gi: (i, gi, 0, 0)),
+        pl.BlockSpec((tb, 1, 1, dv), lambda i, gi: (i, gi, 0, 0)),
         pl.BlockSpec((1, d, m), lambda i, gi: (gi, 0, 0)),
     ]
-    inputs = [q, k, v, a]
+    inputs = [q, k.reshape(b, g, 1, d), v.reshape(b, g, 1, dv), a]
     if m_mat is not None:
         r = m_mat.shape[-2]
         in_specs.append(pl.BlockSpec((1, r, d), lambda i, gi: (gi, 0, 0)))
@@ -165,11 +170,11 @@ def prf_fused_decode_fwd(q: Array, k: Array, v: Array, a: Array,
         kernel = functools.partial(_no_mmat_kernel, _kernel)
     n_lead = len(inputs)
     in_specs += [
-        pl.BlockSpec((tb, 1), lambda i, gi: (i, gi)),
+        pl.BlockSpec((tb, 1, 1, 1), lambda i, gi: (i, gi, 0, 0)),
         pl.BlockSpec((tb, 1, hg, m, dv), lambda i, gi: (i, gi, 0, 0, 0)),
         pl.BlockSpec((tb, 1, hg, m), lambda i, gi: (i, gi, 0, 0)),
     ]
-    inputs += [c.astype(jnp.float32), s, z]
+    inputs += [c.astype(jnp.float32).reshape(b, g, 1, 1), s, z]
 
     out, s_new, z_new, c_new = pl.pallas_call(
         functools.partial(kernel, stabilize=stabilize, eps=eps),
@@ -180,22 +185,22 @@ def prf_fused_decode_fwd(q: Array, k: Array, v: Array, a: Array,
             pl.BlockSpec((tb, 1, hg, m, dv),
                          lambda i, gi: (i, gi, 0, 0, 0)),
             pl.BlockSpec((tb, 1, hg, m), lambda i, gi: (i, gi, 0, 0)),
-            pl.BlockSpec((tb, 1), lambda i, gi: (i, gi)),
+            pl.BlockSpec((tb, 1, 1, 1), lambda i, gi: (i, gi, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, g, hg, dv), jnp.float32),
             jax.ShapeDtypeStruct((b, g, hg, m, dv), jnp.float32),
             jax.ShapeDtypeStruct((b, g, hg, m), jnp.float32),
-            jax.ShapeDtypeStruct((b, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, g, 1, 1), jnp.float32),
         ),
         # the slot pool (s, z, c) is updated IN PLACE: input n_lead is
         # c -> output 3, n_lead+1 is s -> output 1, n_lead+2 is z -> 2
         input_output_aliases={n_lead: 3, n_lead + 1: 1, n_lead + 2: 2},
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(*inputs)
-    return out, s_new, z_new, c_new
+    return out, s_new, z_new, c_new.reshape(b, g)
 
 
 def _no_mmat_kernel(kernel, q_ref, k_ref, v_ref, a_ref, c_ref, s_ref,

@@ -131,8 +131,7 @@ def prf_fused_prefill_ref(q: Array, k: Array, v: Array, a: Array,
     precomposed (W M)^T; m_mat: (G, r, d) or None (isotropic norm);
     s: (B, G, Hg, m, dv); z: (B, G, Hg, m); c: (B, G); valid_len:
     (B,) int32 or None (all rows full). Returns (out (B, G, Hg, L, dv)
-    f32, s_new, z_new, c_new), with outputs at masked positions
-    garbage by contract.
+    f32, s_new, z_new, c_new), with outputs 0 at masked positions.
     """
     f32 = jnp.float32
     q, k, v, a, s, z, c = (t.astype(f32)
@@ -169,6 +168,7 @@ def prf_fused_prefill_ref(q: Array, k: Array, v: Array, a: Array,
         kf = jnp.exp(kraw) * inv_sqrt_m
         qf = jnp.exp(qraw) * inv_sqrt_m
     kf = jnp.where(valid[:, None, :, None], kf, 0.0)
+    qf = jnp.where(valid[:, None, None, :, None], qf, 0.0)
 
     kfb = jnp.broadcast_to(kf[:, :, None], (b, g, hg, l, m))
     vb = jnp.broadcast_to(v[:, :, None], (b, g, hg, l, dv))

@@ -4,13 +4,14 @@
     o_t = r_t^T (S_{t-1} + diag(u) k_t v_t^T)
 
 Grid: (batch*heads) parallel x sequence-chunks sequential; the (dh x dh)
-state S is carried in VMEM scratch across chunks. Within a chunk the
-recurrence is stepped with an in-register fori_loop — per step the work is
-three (dh x dh) VPU element-wise ops + one (1 x dh)(dh x dh) matvec, all
-resident in VMEM (dh = 64 for every RWKV-6 size). The data-dependent decay
-w_t (the "Finch" feature) rules out the pure-matmul chunk form without
-log-space renormalization; the in-VMEM stepped form sidesteps that
-stability issue (see ref.wkv6_ref for the oracle).
+state is carried transposed (Sᵀ) in VMEM scratch across chunks. Within a
+chunk the recurrence is stepped with a fori_loop over token rows read
+from the refs — per step the work is one (dh x dh) VPU decay, one rank-1
+kᵀv matmul and one (1 x dh)(dh x dh) matvec, all resident in VMEM
+(dh = 64 for every RWKV-6 size). The data-dependent decay w_t (the
+"Finch" feature) rules out the pure-matmul chunk form without log-space
+renormalization; the in-VMEM stepped form sidesteps that stability
+issue (see ref.wkv6_ref for the oracle).
 
 VMEM per grid step (f32): 4*T*dh (r,k,v,w) + dh^2 (S) + T*dh (o)
   = T=256, dh=64: ~350 KB.
@@ -24,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
-
 Array = jax.Array
 
 
@@ -36,25 +35,26 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, t: int):
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    r = r_ref[0].astype(jnp.float32)     # (T, dh)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)   # (1, dh)
 
-    def step(i, carry):
-        s, o_acc = carry
-        kv = k[i][:, None] * v[i][None, :]              # (dh, dh)
-        o_i = (r[i][None, :] @ (s + u.T * kv))[0]       # (dh,)
-        s = w[i][:, None] * s + kv
-        o_acc = jax.lax.dynamic_update_index_in_dim(o_acc, o_i, i, 0)
-        return s, o_acc
+    def step(i, st):
+        # st is Sᵀ, so the per-key decay w scales its COLUMNS: a (1, dh)
+        # row broadcast. One token's rows are read from and written to
+        # the refs (Mosaic has no dynamic slice of an in-register value).
+        r = r_ref[0, pl.ds(i, 1), :].astype(jnp.float32)    # (1, dh)
+        k = k_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        w = w_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        # o = r (S + diag(u) kᵀv) = r S + (r·(u∘k)) v
+        o_i = jax.lax.dot_general(r, st, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32) \
+            + jnp.sum(r * u * k, axis=1, keepdims=True) * v
+        o_ref[0, pl.ds(i, 1), :] = o_i.astype(o_ref.dtype)
+        vk = jax.lax.dot_general(v, k, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return st * w + vk                                  # (S')ᵀ
 
-    s0 = s_ref[...]
-    o0 = jnp.zeros((t, v.shape[1]), jnp.float32)
-    s_fin, o = jax.lax.fori_loop(0, t, step, (s0, o0))
-    s_ref[...] = s_fin
-    o_ref[0] = o.astype(o_ref.dtype)
+    s_ref[...] = jax.lax.fori_loop(0, t, step, s_ref[...])
 
 
 def wkv6_fwd(r: Array, k: Array, v: Array, w: Array, u: Array, *,
@@ -89,7 +89,7 @@ def wkv6_fwd(r: Array, k: Array, v: Array, w: Array, u: Array, *,
         out_shape=jax.ShapeDtypeStruct((n, lp, dh), v.dtype),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(r, k, v, w, u.reshape(1, dh))
     return out[:, :l]

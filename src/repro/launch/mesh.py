@@ -1,9 +1,18 @@
 """Mesh builders. Functions, not module constants — importing this module
 never touches jax device state (required for the dry-run's
-xla_force_host_platform_device_count to win the init race)."""
+xla_force_host_platform_device_count to win the init race).
+
+Every mesh here has ``Auto`` axes: the partition specs of
+``repro.parallel.sharding`` are placement hints that XLA propagates
+through the program (``jax.make_mesh`` would default to ``Explicit``
+axes, under which e.g. the embedding gather on a model-sharded table is
+a sharding-type error)."""
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,16 +24,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     used."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
+    n = math.prod(shape)
     devs = jax.devices()
-    if len(devs) == n:
-        return jax.make_mesh(shape, axes)
-    if len(devs) > n:
-        import numpy as np
-        return jax.sharding.Mesh(
-            np.asarray(devs[:n]).reshape(shape), axes)
+    if len(devs) >= n:
+        return make_mesh_for_shape(shape, axes, devices=devs[:n])
     raise ValueError(
         f"need {n} devices for mesh {dict(zip(axes, shape))}, have "
         f"{len(devs)} — run under dryrun.py (it sets "
@@ -33,9 +36,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU runs)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh_for_shape((data, model), ("data", "model"))
 
 
-def make_mesh_for_shape(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary topology (elastic-restart path uses this after a shrink)."""
-    return jax.make_mesh(shape, axes)
+def make_mesh_for_shape(shape: tuple[int, ...], axes: tuple[str, ...],
+                        devices=None):
+    """Arbitrary topology (elastic-restart path uses this after a shrink),
+    over ``devices`` (default: all of them)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

@@ -24,6 +24,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 
 import jax
@@ -37,6 +38,7 @@ from repro.serving.request import shared_prefix_requests, \
     synthetic_requests
 from repro import checkpoint as ckpt_lib
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import setup_compile_cache
 
 
 def _parse_range(spec: str) -> tuple[int, int]:
@@ -47,12 +49,17 @@ def _parse_range(spec: str) -> tuple[int, int]:
     return int(spec), int(spec)
 
 
-def main():
+def main(argv: list[str] | None = None):
+    """Serve synthetic traffic as the CLI arguments say and print the
+    report. Returns (engine, results) for callers that check them."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--kernel", default=None,
                     help="exact|performer|darkformer|lfk (default: config)")
+    ap.add_argument("--dtype", default=None,
+                    help="param/activation dtype, e.g. float32 "
+                         "(default: config)")
     ap.add_argument("--slots", type=int, default=4,
                     help="decode slots (max concurrent sequences)")
     ap.add_argument("--max-len", type=int, default=256,
@@ -120,7 +127,8 @@ def main():
     ap.add_argument("--load", default=None, help="checkpoint dir")
     ap.add_argument("--mesh-data", type=int, default=1)
     ap.add_argument("--mesh-model", type=int, default=1)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = cfgs.get_config(args.arch, reduced=args.reduced)
     if args.kernel:
@@ -131,6 +139,8 @@ def main():
             raise SystemExit(f"unservable --kernel {args.kernel!r} "
                              f"(choose from {', '.join(servable)})")
         cfg = cfgs.darkify(cfg, args.kernel, cfg.attn.num_features)
+    if args.dtype:
+        cfg = dataclasses.replace(cfg, dtype=args.dtype)
     if args.use_kernel:
         if cfg.attn.kind == "exact":
             # previously accepted silently while doing nothing — the
@@ -139,7 +149,6 @@ def main():
                   "kernel (Pallas paths exist for the PRF kinds only); "
                   "ignoring the flag", file=sys.stderr)
         else:
-            import dataclasses
             cfg = dataclasses.replace(cfg, use_kernel=True)
     if cfg.modality != "text":
         raise SystemExit("serving engine drives text decode only")
@@ -190,7 +199,8 @@ def main():
         raise SystemExit(f"bad request: {e}")
 
     print(f"serving {args.requests} requests over {args.slots} slots "
-          f"(kernel={cfg.attn.kind}, max_len={args.max_len}, "
+          f"(kernel={cfg.attn.kind}, dtype={cfg.dtype}, "
+          f"max_len={args.max_len}, "
           f"rate={args.rate or 'batch'}"
           + (f", mesh={args.mesh_data}x{args.mesh_model}" if pool_mesh
              is not None else "") + ")")
@@ -246,6 +256,7 @@ def main():
           f"batched calls ({st['prefill_rows_per_call']:.1f} rows/call, "
           f"batch occupancy {st['prefill_batch_occupancy'] * 100:.0f}%, "
           f"max {st['max_prefill_tokens_per_step']} tokens per step)")
+    return engine, results
 
 
 if __name__ == "__main__":

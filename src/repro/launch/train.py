@@ -27,6 +27,7 @@ from repro import configs as cfgs
 from repro.data import SyntheticLM, SyntheticAudio, SyntheticVLM, C4Mock
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import lm
 from repro.optim import AdamWConfig, adamw_init
 from repro.optim.schedules import cosine_warmup
@@ -49,7 +50,10 @@ def make_data(cfg, args):
     return SyntheticLM(cfg.vocab, args.seq, args.batch, seed=args.seed)
 
 
-def main():
+def main(argv: list[str] | None = None) -> tuple[dict, list[dict]]:
+    """Train as the CLI arguments say. Returns the final
+    {"params", "opt"} state and the logged metrics (one dict per
+    ``--log-every`` step, and the last step)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true",
@@ -77,7 +81,8 @@ def main():
     ap.add_argument("--mesh-model", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = cfgs.get_config(args.arch, reduced=args.reduced)
     if args.kernel:
@@ -151,6 +156,7 @@ def main():
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:
             json.dump(metrics_log, f, indent=1)
+    return state, metrics_log
 
 
 if __name__ == "__main__":

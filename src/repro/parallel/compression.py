@@ -25,20 +25,12 @@ def init_error_feedback(grads: PyTree) -> PyTree:
         lambda g: jnp.zeros(g.shape, jnp.float32), grads)
 
 
-def _axis_size(a):
-    # jax.lax.axis_size only exists on newer jax; psum of a unit scalar is
-    # the portable spelling (constant-folded, no collective emitted).
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(jnp.ones((), jnp.int32), a)
-
-
 def _psum_mean(x, axis_names):
     y = jax.lax.psum(x, axis_names)
     n = 1
     for a in (axis_names if isinstance(axis_names, tuple) else
               (axis_names,)):
-        n = n * _axis_size(a)
+        n = n * jax.lax.axis_size(a)
     return y / n
 
 

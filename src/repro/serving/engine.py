@@ -589,6 +589,23 @@ class ServingEngine:
             path = "jnp"
         return {"prefill_path": path, "decode_path": path}
 
+    def compiled_text(self, rows: int, length: int) -> dict[str, str]:
+        """HLO text of the decode step and of a packed (rows, length)
+        prefill chunk, as XLA compiles them for this engine's pools —
+        e.g. to confirm that the Pallas kernels are in the program
+        (``tpu_custom_call``) and were not interpreted."""
+        toks = jnp.zeros((rows, length), jnp.int32)
+        idx = jnp.arange(rows, dtype=jnp.int32)
+        active = jnp.asarray(self._active)
+        head = (self._step_params, self._decode_proj)
+        pages = (self._pages,) if self._paged else ()
+        dec = self._decode_fn.lower(*head, self.pool, *pages, self._feed,
+                                    active, False)
+        pre = self._prefill_fn.lower(*head, self.staging, *pages, toks,
+                                     idx, None)
+        return {"decode": dec.compile().as_text(),
+                "prefill": pre.compile().as_text()}
+
     def _snapshot_to_device(self, tree):
         """Promote a host-tier prefix snapshot back to device, with the
         pools' mesh sharding when the engine runs sharded (the b=1 slot

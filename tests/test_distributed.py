@@ -30,10 +30,7 @@ def test_grad_compression_shard_map():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map        # jax >= 0.6
-        except ImportError:                  # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_local_mesh
         from repro.parallel import compressed_psum_mean, init_error_feedback
 
@@ -75,10 +72,7 @@ def test_int8_error_feedback_converges():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map        # jax >= 0.6
-        except ImportError:                  # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_local_mesh
         from repro.parallel import compressed_psum_mean
 
@@ -188,10 +182,7 @@ def test_sequence_parallel_state_combine():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map        # jax >= 0.6
-        except ImportError:                  # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_local_mesh
         from repro.core.linear_attention import (
             LinearState, sequence_parallel_state_combine)

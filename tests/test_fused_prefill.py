@@ -106,6 +106,19 @@ def test_fused_prefill_kernel_vs_oracle(b, g, hg, d, r, m, dv, l, dark,
     _assert_close(out, exp, l, valid_len, (b, g, hg, l, chunk))
 
 
+def test_fused_prefill_padded_positions_stay_finite():
+    """Rows with no valid position in a whole internal chunk (a 0-length
+    row, rows ending before the last chunk) still give finite outputs
+    everywhere: NaN at padded positions would reach the next layer's v
+    and, through 0·NaN in kfᵀv, its state."""
+    args = _fused_inputs(4, 2, 2, 8, 4, 16, 8, 10, True, seed=3)
+    vl = jnp.asarray((0, 3, 10, 7), jnp.int32)
+    out = prf_fused_prefill_fwd(*args, vl, chunk=4, block_b=2,
+                                interpret=True)
+    for o in out:
+        assert np.isfinite(np.asarray(o)).all()
+
+
 @settings(deadline=None, max_examples=10)
 @given(st.integers(0, 10_000), st.integers(1, 4), st.integers(1, 3),
        st.integers(1, 3), st.integers(1, 10), st.booleans(),
@@ -366,3 +379,25 @@ def test_engine_stats_report_exact_path():
     eng = ServingEngine(params, cfg_ex, max_slots=2, max_len=32)
     assert eng.stats["prefill_path"] == "exact"
     assert eng.stats["decode_path"] == "exact"
+
+
+def test_engine_blocking_admission_longer_than_kernel_chunk():
+    """Blocking admission packs whole prompts into one call, so a short
+    row shares a call longer than the kernel's 256-token chunk with a
+    long one and holds no position in its last chunk: the fused engine
+    still streams identically to the jnp engine."""
+    from repro.serving import Request, ServingEngine
+    cfg = cfgs.get_config("smollm-135m", reduced=True)
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    prompts = [jax.random.randint(jax.random.PRNGKey(20 + i), (n,), 0,
+                                  cfg.vocab).tolist()
+               for i, n in enumerate((300, 50))]
+    streams = {}
+    for use_kernel in (False, True):
+        c = dataclasses.replace(cfg, use_kernel=use_kernel)
+        eng = ServingEngine(params, c, max_slots=2, max_len=320)
+        uids = [eng.submit(Request(prompt=p, max_new_tokens=4))
+                for p in prompts]
+        got = {r.uid: r.tokens for r in eng.run()}
+        streams[use_kernel] = [got[u] for u in uids]
+    assert streams[False] == streams[True]

@@ -75,8 +75,9 @@ def test_serve_launcher_decodes():
     out = run_cmd(["repro.launch.serve", "--arch", "smollm-135m",
                    "--reduced", "--requests", "3", "--slots", "2",
                    "--prompt-len", "8-16", "--gen", "8",
-                   "--max-len", "48"])
+                   "--max-len", "48", "--dtype", "bfloat16"])
     assert "throughput:" in out and "slot occupancy:" in out
+    assert "dtype=bfloat16" in out
     assert out.count("req ") == 3
 
 

@@ -342,3 +342,13 @@ def test_jnp_carry_oracle_matches_masked_ref():
     np.testing.assert_allclose(np.asarray(out), np.asarray(eo), atol=2e-5)
     np.testing.assert_allclose(np.asarray(s), np.asarray(es), atol=2e-5)
     np.testing.assert_allclose(np.asarray(z), np.asarray(ez), atol=2e-5)
+
+
+def test_jnp_blockwise_matches_masked_ref():
+    """The loop-free chunked form (the training forward) agrees with the
+    O(L^2) masked oracle across several chunks and a padded tail."""
+    qf, kf, v, _, _ = _carry_inputs(2, 29, 16, 8, seed=8)
+    out = la.linear_attention_causal_blockwise(qf, kf, v, chunk=8)
+    expect = ref.linear_attention_causal_ref(qf, kf, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               atol=2e-5)

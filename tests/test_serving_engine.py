@@ -177,3 +177,18 @@ def test_poisson_arrivals_respected():
     all_res = results
     assert sum(1 for r in all_res if r.tokens) >= 1
     assert not eng.has_work
+
+
+@pytest.mark.parametrize("kind", ["darkformer", "exact"])
+def test_compiled_text_covers_decode_and_prefill(kind):
+    """The engine hands back both compiled step programs. Off the TPU the
+    Pallas kernels run interpreted, so no Mosaic custom call is in them
+    (on a TPU chip_smoke.py asserts the opposite)."""
+    cfg = _cfg(kind, use_kernel=kind != "exact")
+    eng = ServingEngine(_params(cfg), cfg, max_slots=2, max_len=32,
+                        chunk_tokens=8)
+    text = eng.compiled_text(rows=2, length=8)
+    assert set(text) == {"decode", "prefill"}
+    for t in text.values():
+        assert t.startswith("HloModule")
+        assert "tpu_custom_call" not in t
