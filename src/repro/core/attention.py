@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core import feature_maps as fm
 from repro.core import linear_attention as la
+from repro.core import scopes
 # module-level: the wrappers resolve interpret-vs-TPU once; importing
 # inside the hot functions re-ran the import machinery on every trace
 from repro.kernels import ops as kops
@@ -82,12 +83,13 @@ def _stab_max(raw: Array, enabled: bool) -> Array:
 
 def _qk_feature_pair(q, k, fparams, cfg: fm.FeatureConfig):
     """q:(B,G,Hg,L,d), k:(B,G,1,L,d) -> qf:(B,G,Hg,L,m), kf:(B,G,1,L,m)."""
-    inv_sqrt_m = cfg.num_features ** -0.5
-    qraw = _raw_logits(q, fparams, cfg.kind)
-    kraw = _raw_logits(k, fparams, cfg.kind)
-    qf = jnp.exp(qraw - _stab_max(qraw, cfg.stabilize)) * inv_sqrt_m
-    kc = _stab_max(kraw, cfg.stabilize)
-    kf = jnp.exp(kraw - kc) * inv_sqrt_m
+    with jax.named_scope(scopes.PRF_FEATURES):
+        inv_sqrt_m = cfg.num_features ** -0.5
+        qraw = _raw_logits(q, fparams, cfg.kind)
+        kraw = _raw_logits(k, fparams, cfg.kind)
+        qf = jnp.exp(qraw - _stab_max(qraw, cfg.stabilize)) * inv_sqrt_m
+        kc = _stab_max(kraw, cfg.stabilize)
+        kf = jnp.exp(kraw - kc) * inv_sqrt_m
     return qf, kf, kc
 
 
@@ -104,27 +106,28 @@ def _resume_qk_features(qs, ks, fparams, cfg: fm.FeatureConfig, c_in,
     state advances exactly as its unpadded (B=1) counterpart would, and
     a row with no valid position (its q max is -inf) stays finite.
     Returns (qf, kf, c_new, rescale)."""
-    inv_sqrt_m = cfg.num_features ** -0.5
-    qraw = _raw_logits(qs, fparams, cfg.kind)
-    kraw = _raw_logits(ks, fparams, cfg.kind)
-    if valid_mask is not None:
-        neg = jnp.finfo(jnp.float32).min
-        qraw_m = jnp.where(valid_mask, qraw, neg)
-        kraw_m = jnp.where(valid_mask, kraw, neg)
-    else:
-        qraw_m, kraw_m = qraw, kraw
-    qf = jnp.exp(qraw - _stab_max(qraw_m, cfg.stabilize)) * inv_sqrt_m
-    if cfg.stabilize:
-        c_new = jnp.maximum(c_in, _stab_max(kraw_m, True))
-    else:
-        # unstabilized features carry c == 0 (the init state's -inf
-        # sentinel only ever zeroes an all-zero fresh state)
-        c_new = jnp.zeros_like(c_in)
-    rescale = jnp.exp(c_in - c_new)                    # <= 1
-    kf = jnp.exp(kraw - c_new) * inv_sqrt_m
-    if valid_mask is not None:
-        qf = jnp.where(valid_mask, qf, 0.0)
-        kf = jnp.where(valid_mask, kf, 0.0)
+    with jax.named_scope(scopes.PRF_FEATURES):
+        inv_sqrt_m = cfg.num_features ** -0.5
+        qraw = _raw_logits(qs, fparams, cfg.kind)
+        kraw = _raw_logits(ks, fparams, cfg.kind)
+        if valid_mask is not None:
+            neg = jnp.finfo(jnp.float32).min
+            qraw_m = jnp.where(valid_mask, qraw, neg)
+            kraw_m = jnp.where(valid_mask, kraw, neg)
+        else:
+            qraw_m, kraw_m = qraw, kraw
+        qf = jnp.exp(qraw - _stab_max(qraw_m, cfg.stabilize)) * inv_sqrt_m
+        if cfg.stabilize:
+            c_new = jnp.maximum(c_in, _stab_max(kraw_m, True))
+        else:
+            # unstabilized features carry c == 0 (the init state's -inf
+            # sentinel only ever zeroes an all-zero fresh state)
+            c_new = jnp.zeros_like(c_in)
+        rescale = jnp.exp(c_in - c_new)                    # <= 1
+        kf = jnp.exp(kraw - c_new) * inv_sqrt_m
+        if valid_mask is not None:
+            qf = jnp.where(valid_mask, qf, 0.0)
+            kf = jnp.where(valid_mask, kf, 0.0)
     return qf, kf, c_new, rescale
 
 
@@ -147,16 +150,19 @@ def rf_attention(q: Array, k: Array, v: Array, fparams: Optional[dict],
         out = la.random_attention(baseline_key, v, causal=causal)
         return jnp.broadcast_to(out, (b, g, hg, l, dv))
 
-    qs, ks = _scale_qk(q, k)
+    with jax.named_scope(scopes.PRF_FEATURES):
+        qs, ks = _scale_qk(q, k)
     qf, kf, _ = _qk_feature_pair(qs, ks, fparams, cfg)
-    kf = jnp.broadcast_to(kf, (b, g, hg, l, cfg.num_features))
-    vv = jnp.broadcast_to(v, (b, g, hg, l, dv))
-    if not causal:
-        return la.linear_attention_noncausal(qf, kf, vv, eps=cfg.eps)
-    if use_kernel:
-        return kops.linear_attention_causal(qf, kf, vv, eps=cfg.eps)
-    return la.linear_attention_causal_blockwise(qf, kf, vv, chunk=chunk,
-                                                eps=cfg.eps)
+    with jax.named_scope(scopes.PRF_MIX):
+        kf = jnp.broadcast_to(kf, (b, g, hg, l, cfg.num_features))
+        vv = jnp.broadcast_to(v, (b, g, hg, l, dv))
+        if not causal:
+            return la.linear_attention_noncausal(qf, kf, vv, eps=cfg.eps)
+        if use_kernel:
+            return kops.linear_attention_causal(qf, kf, vv, eps=cfg.eps)
+        return la.linear_attention_causal_blockwise(qf, kf, vv,
+                                                    chunk=chunk,
+                                                    eps=cfg.eps)
 
 
 class AttnServeState(NamedTuple):

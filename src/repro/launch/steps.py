@@ -7,6 +7,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.models import lm
 from repro.optim import AdamWConfig, adamw_init, adamw_update
 
@@ -24,16 +25,17 @@ def make_train_step(cfg: lm.ModelConfig, opt_cfg: AdamWConfig,
         rng = jax.random.fold_in(jax.random.PRNGKey(0), step)
         (loss, metrics), grads = jax.value_and_grad(
             lm.loss_fn, has_aux=True)(params, cfg, batch, rng)
-        if freeze is not None:
-            flat, tdef = jax.tree_util.tree_flatten_with_path(grads)
-            flat = [(p, jnp.zeros_like(g)
-                     if freeze(jax.tree_util.keystr(p)) else g)
-                    for p, g in flat]
-            grads = jax.tree_util.tree_unflatten(tdef,
-                                                 [g for _, g in flat])
-        lr = schedule(step)
-        params, opt_state, om = adamw_update(params, grads, opt_state,
-                                             opt_cfg, lr)
+        with jax.named_scope(scopes.OPTIMIZER):
+            if freeze is not None:
+                flat, tdef = jax.tree_util.tree_flatten_with_path(grads)
+                flat = [(p, jnp.zeros_like(g)
+                         if freeze(jax.tree_util.keystr(p)) else g)
+                        for p, g in flat]
+                grads = jax.tree_util.tree_unflatten(tdef,
+                                                     [g for _, g in flat])
+            lr = schedule(step)
+            params, opt_state, om = adamw_update(params, grads, opt_state,
+                                                 opt_cfg, lr)
         return params, opt_state, {**metrics, **om}
 
     return train_step

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import attention as rfa
 from repro.core import feature_maps as fm
+from repro.core import scopes
 from repro.models import layers as ll
 
 Array = jax.Array
@@ -40,28 +41,30 @@ def attn_init(key, d_model: int, n_heads: int, n_kv: int, d_head: int,
 
 def _project(params, x, n_heads, n_kv, d_head, qk_norm, positions,
              rope_theta):
-    b, l, _ = x.shape
-    hg = n_heads // n_kv
-    q = (x @ params["wq"]).reshape(b, l, n_kv, hg, d_head)
-    k = (x @ params["wk"]).reshape(b, l, n_kv, 1, d_head)
-    v = (x @ params["wv"]).reshape(b, l, n_kv, 1, d_head)
-    q = jnp.moveaxis(q, 1, 3)          # (B, G, Hg, L, dh)
-    k = jnp.moveaxis(k, 1, 3)
-    v = jnp.moveaxis(v, 1, 3)
-    if qk_norm:
-        q = ll.rmsnorm(params["q_norm"], q)
-        k = ll.rmsnorm(params["k_norm"], k)
-    if rope_theta > 0:
-        q = ll.apply_rope(q, positions, rope_theta)
-        k = ll.apply_rope(k, positions, rope_theta)
+    with jax.named_scope(scopes.ATTN_IN):
+        b, l, _ = x.shape
+        hg = n_heads // n_kv
+        q = (x @ params["wq"]).reshape(b, l, n_kv, hg, d_head)
+        k = (x @ params["wk"]).reshape(b, l, n_kv, 1, d_head)
+        v = (x @ params["wv"]).reshape(b, l, n_kv, 1, d_head)
+        q = jnp.moveaxis(q, 1, 3)          # (B, G, Hg, L, dh)
+        k = jnp.moveaxis(k, 1, 3)
+        v = jnp.moveaxis(v, 1, 3)
+        if qk_norm:
+            q = ll.rmsnorm(params["q_norm"], q)
+            k = ll.rmsnorm(params["k_norm"], k)
+        if rope_theta > 0:
+            q = ll.apply_rope(q, positions, rope_theta)
+            k = ll.apply_rope(k, positions, rope_theta)
     return q, k, v
 
 
 def _merge_heads(out, params):
     # out: (B, G, Hg, L, dh) -> (B, L, H*dh) @ wo
-    b, g, hg, l, dh = out.shape
-    out = jnp.moveaxis(out, 3, 1).reshape(b, l, g * hg * dh)
-    return out @ params["wo"]
+    with jax.named_scope(scopes.ATTN_OUT):
+        b, g, hg, l, dh = out.shape
+        out = jnp.moveaxis(out, 3, 1).reshape(b, l, g * hg * dh)
+        return out @ params["wo"]
 
 
 def attn_apply(params: dict, x: Array, cfg: fm.FeatureConfig, *,

@@ -14,6 +14,7 @@ configurable remat policy.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import feature_maps as fm
+from repro.core import scopes
 from repro.models import layers as ll
 from repro.models import attention_block as ab
 from repro.models import recurrent as rec
@@ -174,7 +176,10 @@ def _apply_block(params, x, cfg: ModelConfig, kind: str, *,
     ``prf_fused_decode``).
     """
     aux = jnp.zeros((), jnp.float32)
-    h = ll.apply_norm(cfg.norm_kind, params["ln1"], x)
+    # the attention's pre-norm is counted with its projections
+    with (jax.named_scope(scopes.ATTN_IN) if kind in ("attn", "local")
+          else contextlib.nullcontext()):
+        h = ll.apply_norm(cfg.norm_kind, params["ln1"], x)
     new_state = state
     common = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv, d_head=cfg.head_dim,
                   qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta)
@@ -197,13 +202,9 @@ def _apply_block(params, x, cfg: ModelConfig, kind: str, *,
                 params["attn"], h, state, cfg.attn, position=position,
                 window=window, use_kernel=cfg.use_kernel, proj=proj,
                 **common)
-        x = x + mix
-        h2 = ll.apply_norm(cfg.norm_kind, params["ln2"], x)
-        if cfg.moe:
-            f, aux = ll.moe_apply(params["ffn"], h2, cfg.moe)
-        else:
-            f = ll.mlp_apply(params["ffn"], h2, cfg.mlp_kind)
-        x = x + f
+        with jax.named_scope(scopes.ATTN_OUT):
+            x = x + mix
+        x, aux = _ffn(params["ln2"], params["ffn"], x, aux, cfg)
     elif kind == "rec":
         if mode == "train":
             mix, _ = rec.rglru_apply(params["rec"], h, None)
@@ -211,12 +212,7 @@ def _apply_block(params, x, cfg: ModelConfig, kind: str, *,
             mix, new_state = rec.rglru_apply(params["rec"], h, state,
                                              valid_len=valid_len)
         x = x + mix
-        h2 = ll.apply_norm(cfg.norm_kind, params["ln2"], x)
-        if cfg.moe:
-            f, aux = ll.moe_apply(params["ffn"], h2, cfg.moe)
-        else:
-            f = ll.mlp_apply(params["ffn"], h2, cfg.mlp_kind)
-        x = x + f
+        x, aux = _ffn(params["ln2"], params["ffn"], x, aux, cfg)
     elif kind == "rwkv":
         if mode == "train":
             mix, _ = rec.rwkv6_apply(params["tmix"], h, cfg.n_heads, None)
@@ -237,32 +233,46 @@ def _apply_block(params, x, cfg: ModelConfig, kind: str, *,
     return x, aux, new_state
 
 
+def _ffn(norm, ffn, x, aux, cfg: ModelConfig):
+    """Pre-norm FFN (dense or experts) plus its residual. Returns (x,
+    aux), with the experts' load-balancing loss in place of ``aux``."""
+    with jax.named_scope(scopes.MLP):
+        h = ll.apply_norm(cfg.norm_kind, norm, x)
+        if cfg.moe:
+            f, aux = ll.moe_apply(ffn, h, cfg.moe)
+        else:
+            f = ll.mlp_apply(ffn, h, cfg.mlp_kind)
+        return x + f, aux
+
+
 def _embed_inputs(params, cfg: ModelConfig, batch: dict) -> Array:
-    dt = cfg.param_dtype
-    if cfg.modality == "audio":
-        x = batch["frames"].astype(dt)
-        if "mask" in batch:
-            me = params["mask_embed"].astype(dt)
-            x = jnp.where(batch["mask"][..., None], me[None, None], x)
-        return x
-    tok = params["embed"][batch["tokens"]]
-    if cfg.embed_scale:
-        tok = tok * jnp.asarray(cfg.d_model ** 0.5, dt)
-    if cfg.modality == "vlm":
-        patches = batch["patch_embeds"].astype(dt)
-        return jnp.concatenate([patches, tok.astype(dt)], axis=1)
-    return tok.astype(dt)
+    with jax.named_scope(scopes.EMBED):
+        dt = cfg.param_dtype
+        if cfg.modality == "audio":
+            x = batch["frames"].astype(dt)
+            if "mask" in batch:
+                me = params["mask_embed"].astype(dt)
+                x = jnp.where(batch["mask"][..., None], me[None, None], x)
+            return x
+        tok = params["embed"][batch["tokens"]]
+        if cfg.embed_scale:
+            tok = tok * jnp.asarray(cfg.d_model ** 0.5, dt)
+        if cfg.modality == "vlm":
+            patches = batch["patch_embeds"].astype(dt)
+            return jnp.concatenate([patches, tok.astype(dt)], axis=1)
+        return tok.astype(dt)
 
 
 def _logits(params, cfg: ModelConfig, x: Array) -> Array:
-    x = ll.apply_norm(cfg.norm_kind, params["final_norm"], x)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-    if cfg.logit_softcap > 0:
-        c = cfg.logit_softcap
-        logits = c * jnp.tanh(logits / c)
-    return logits
+    with jax.named_scope(scopes.LM_HEAD):
+        x = ll.apply_norm(cfg.norm_kind, params["final_norm"], x)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
+        if cfg.logit_softcap > 0:
+            c = cfg.logit_softcap
+            logits = c * jnp.tanh(logits / c)
+        return logits
 
 
 def forward_train(params, cfg: ModelConfig, batch: dict,
@@ -387,21 +397,22 @@ def whitening_calibrate(params, cfg: ModelConfig, batch: dict,
 def loss_fn(params, cfg: ModelConfig, batch: dict,
             rng: Optional[Array] = None) -> tuple[Array, dict]:
     logits, aux = forward_train(params, cfg, batch, rng)
-    labels = batch["labels"]
-    if cfg.modality == "vlm":
-        logits = logits[:, -labels.shape[1]:]        # loss on text positions
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    ll_tok = jnp.take_along_axis(logits, labels[..., None],
-                                 axis=-1)[..., 0] - logz
-    if cfg.modality == "audio" and "mask" in batch:
-        wmask = batch["mask"].astype(jnp.float32)
-    else:
-        wmask = (labels >= 0).astype(jnp.float32)
-    denom = jnp.maximum(jnp.sum(wmask), 1.0)
-    ce = -jnp.sum(ll_tok * wmask) / denom
-    zl = cfg.z_loss * jnp.sum(jnp.square(logz) * wmask) / denom
-    loss = ce + zl + aux
-    acc = jnp.sum((jnp.argmax(logits, -1) == labels) * wmask) / denom
+    with jax.named_scope(scopes.LOSS):
+        labels = batch["labels"]
+        if cfg.modality == "vlm":
+            logits = logits[:, -labels.shape[1]:]    # loss on text positions
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        ll_tok = jnp.take_along_axis(logits, labels[..., None],
+                                     axis=-1)[..., 0] - logz
+        if cfg.modality == "audio" and "mask" in batch:
+            wmask = batch["mask"].astype(jnp.float32)
+        else:
+            wmask = (labels >= 0).astype(jnp.float32)
+        denom = jnp.maximum(jnp.sum(wmask), 1.0)
+        ce = -jnp.sum(ll_tok * wmask) / denom
+        zl = cfg.z_loss * jnp.sum(jnp.square(logz) * wmask) / denom
+        loss = ce + zl + aux
+        acc = jnp.sum((jnp.argmax(logits, -1) == labels) * wmask) / denom
     return loss, {"loss": loss, "ce": ce, "z_loss": zl, "aux": aux,
                   "accuracy": acc}
 
