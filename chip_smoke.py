@@ -4,8 +4,10 @@ One process (a chip belongs to one process at a time), in phases; each
 prints its own lines and a verdict:
 
   a. device   JAX sees a TPU. There is no CPU fallback.
-  b. kernels  the seven Pallas kernels, compiled (``interpret=False``) at
-              smollm-135m widths, against their ``kernels/ref.py`` oracles.
+  b. kernels  the Pallas kernels, compiled (``interpret=False``) at
+              smollm-135m widths, against their ``kernels/ref.py`` oracles;
+              the training mix's pair (forward and gradients) against
+              the XLA blockwise path, which is printed beside it.
   c. serve    smollm-135m at full width with ``--use-kernel`` through
               ``repro.launch.serve.main``: 8 requests, prompts of 64-512
               tokens, chunked prefill, 32 generated tokens each. Both
@@ -48,9 +50,10 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro import configs as cfgs  # noqa: E402
-from repro.kernels import ref  # noqa: E402
+from repro.core import linear_attention as la  # noqa: E402
+from repro.kernels import ops, ref  # noqa: E402
 from repro.kernels.linear_attn_scan import (  # noqa: E402
-    linear_attention_causal_carry_fwd, linear_attention_causal_fwd)
+    linear_attention_causal_carry_fwd)
 from repro.kernels.prf_decode_step import prf_decode_step_fwd  # noqa: E402
 from repro.kernels.prf_featmap import prf_featmap_fwd  # noqa: E402
 from repro.kernels.prf_fused_decode import prf_fused_decode_fwd  # noqa: E402
@@ -153,6 +156,35 @@ def phase_device(chips: int) -> None:
 # b. kernels
 # ---------------------------------------------------------------------------
 
+# the training cell's floor under the PRF attention denominator
+MIX_EPS = 1e-30
+
+
+def ops_mix(qf, kf, v):
+    """The training step's causal PRF mix as it runs on the chip: the
+    Pallas pair (one row per KV group, a backward of its own)."""
+    return ops.linear_attention_causal(qf, kf, v, eps=MIX_EPS)
+
+
+def _blockwise(qf, kf, v):
+    """The XLA path the pair replaced (and its oracle)."""
+    return la.linear_attention_causal_blockwise(
+        qf, jnp.broadcast_to(kf, qf.shape),
+        jnp.broadcast_to(v, qf.shape[:-1] + v.shape[-1:]), eps=MIX_EPS)
+
+
+def _train_mix(mix):
+    """(out, dqf, dkf, dv) of ``mix`` for an output cotangent ``g``."""
+    def run(qf, kf, v, g, interpret=False):
+        out, vjp = jax.vjp(mix, qf, kf, v)
+        return (out,) + vjp(g)
+    return run
+
+
+def _mix_oracle(qf, kf, v, g):
+    return _train_mix(_blockwise)(qf, kf, v, g)
+
+
 def _kernel_cases(seed: int = 0):
     """(name, kernel, oracle, arguments) at smollm-135m attention
     widths."""
@@ -161,7 +193,7 @@ def _kernel_cases(seed: int = 0):
     hg = cfg.n_heads // g
     slots, rows, l = 8, 8, 512
     n = rows * cfg.n_heads
-    ks = iter(jax.random.split(jax.random.PRNGKey(seed), 32))
+    ks = iter(jax.random.split(jax.random.PRNGKey(seed), 48))
 
     def normal(shape, scale=1.0):
         return scale * jax.random.normal(next(ks), shape)
@@ -186,6 +218,8 @@ def _kernel_cases(seed: int = 0):
            jax.random.uniform(next(ks), (rows, g, hg, m)) + 0.5,
            normal((rows, g)), vl)
     lin = (positive((n, l, m)), positive((n, l, m)), normal((n, l, d)))
+    mix = (positive((rows, g, hg, l, m)), positive((rows, g, 1, l, m)),
+           normal((rows, g, 1, l, d)), normal((rows, g, hg, l, d)))
     carry = lin + (normal((n, m, d)),
                    jax.random.uniform(next(ks), (n, m)) + 0.5)
     feat = (normal((rows * l, d), qs), m_mat[0], w[0], jnp.float32(0.0))
@@ -220,16 +254,20 @@ def _kernel_cases(seed: int = 0):
             lambda x: x.astype(jnp.bfloat16).astype(x.dtype)
             if jnp.issubdtype(x.dtype, jnp.floating) else x, args)
 
-    dec, pre, lin, carry, feat, step, wkv = map(
-        bf16_exact, (dec, pre, lin, carry, feat, step, wkv))
+    dec, pre, lin, carry, feat, step, wkv, mix = map(
+        bf16_exact, (dec, pre, lin, carry, feat, step, wkv, mix))
+    # values and their cotangent in bf16, as the model holds them
+    mix = mix[:2] + tuple(x.astype(jnp.bfloat16) for x in mix[2:])
     return [
         ("prf_fused_decode", prf_fused_decode_fwd, ref.prf_fused_decode_ref,
          dec),
         ("prf_fused_prefill", functools.partial(prf_fused_prefill_fwd,
                                                 chunk=CHUNK),
          prefill_ref, pre),
-        ("linear_attention_causal", linear_attention_causal_fwd,
-         ref.linear_attention_causal_ref, lin),
+        ("prf_mix (out, dqf, dkf, dv)", _train_mix(ops_mix), _mix_oracle,
+         mix),
+        ("  the XLA blockwise path", _train_mix(_blockwise),
+         _mix_oracle, mix),
         ("linear_attention_causal_carry", linear_attention_causal_carry_fwd,
          ref.linear_attention_carry_ref, carry),
         ("prf_featmap", prf_featmap_fwd, ref.prf_featmap_ref, feat),

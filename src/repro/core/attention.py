@@ -136,7 +136,13 @@ def rf_attention(q: Array, k: Array, v: Array, fparams: Optional[dict],
                  window: Optional[int] = None, chunk: int = 256,
                  use_kernel: bool = False,
                  baseline_key: Optional[Array] = None) -> Array:
-    """Training-time attention. Returns (B, G, Hg, L, dv)."""
+    """Training-time attention. Returns (B, G, Hg, L, dv).
+
+    The causal PRF mix takes the Pallas pair (KV groups unbroadcast, a
+    backward of its own) wherever ``kops.train_mix_kernel`` says: on
+    one TPU device always, off the TPU when ``use_kernel`` asks;
+    elsewhere ``linear_attention_causal_blockwise``, its oracle.
+    """
     b, g, hg, l, _ = q.shape
     dv = v.shape[-1]
     if cfg.kind == "exact":
@@ -154,12 +160,12 @@ def rf_attention(q: Array, k: Array, v: Array, fparams: Optional[dict],
         qs, ks = _scale_qk(q, k)
     qf, kf, _ = _qk_feature_pair(qs, ks, fparams, cfg)
     with jax.named_scope(scopes.PRF_MIX):
+        if causal and kops.train_mix_kernel(use_kernel):
+            return kops.linear_attention_causal(qf, kf, v, eps=cfg.eps)
         kf = jnp.broadcast_to(kf, (b, g, hg, l, cfg.num_features))
         vv = jnp.broadcast_to(v, (b, g, hg, l, dv))
         if not causal:
             return la.linear_attention_noncausal(qf, kf, vv, eps=cfg.eps)
-        if use_kernel:
-            return kops.linear_attention_causal(qf, kf, vv, eps=cfg.eps)
         return la.linear_attention_causal_blockwise(qf, kf, vv,
                                                     chunk=chunk,
                                                     eps=cfg.eps)

@@ -210,8 +210,11 @@ def linear_attention_causal_blockwise(qf: Array, kf: Array, v: Array,
     num = jnp.einsum("...cqm,...cmd->...cqd", qc, s) + jnp.einsum(
         "...cqk,...ckd->...cqd", local, vc)
     den = jnp.einsum("...cqm,...cm->...cq", qc, z) + jnp.sum(local, axis=-1)
-    out = (num / (den[..., None] + eps)).reshape(*batch, nc * chunk, dv)
-    return out[..., :l, :].astype(v.dtype)
+    # the padded rows are cut before the division: their den is 0, and
+    # the division's backward would square eps there
+    num = num.reshape(*batch, nc * chunk, dv)[..., :l, :]
+    den = den.reshape(*batch, nc * chunk)[..., :l]
+    return (num / (den[..., None] + eps)).astype(v.dtype)
 
 
 class LinearState(NamedTuple):

@@ -4,7 +4,9 @@ Kernels (each: <name>.py pallas_call + BlockSpec, oracle in ref.py, jit'd
 differentiable wrapper in ops.py):
 
   * linear_attn_scan  — chunked causal linear attention (the O(Lmd) scan
-    that replaces the softmax O(L^2 d) matmuls; paper Fig. 1)
+    that replaces the softmax O(L^2 d) matmuls; paper Fig. 1): the
+    training pair (forward and a backward of its own, one KV group per
+    grid row) and the carried-state form that serving prefill resumes
   * prf_featmap       — fused phi(x) = exp(W Mx - ||Mx||^2/2 - c)/sqrt(m)
   * prf_decode_step   — fused one-token serving update of the (S, z)
     prefix state with online-stabilizer rescale (forward-only)

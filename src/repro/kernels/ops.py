@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels, with autodiff.
 
-Forward = Pallas kernel; backward = VJP of the pure-jnp oracle (exact same
-math, so gradients are correct and the kernel stays forward-only). On this
-CPU container the kernels run with interpret=True; on TPU they compile.
-``repro.kernels.USE_INTERPRET`` is resolved once from the backend.
+The causal linear attention has a Pallas backward of its own; the other
+differentiable kernels (feature map, WKV-6) take the VJP of their
+pure-jnp oracle (the same math). Off the TPU the kernels run with
+``interpret=True``; on the TPU they compile (``_use_interpret``).
 """
 from __future__ import annotations
 
@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
-from repro.kernels.linear_attn_scan import (linear_attention_causal_fwd,
-                                            linear_attention_causal_carry_fwd)
+from repro.kernels.linear_attn_scan import (
+    linear_attention_causal_carry_fwd, prf_mix_bwd, prf_mix_fwd)
 from repro.kernels.prf_featmap import prf_featmap_fwd
 
 Array = jax.Array
@@ -25,49 +25,73 @@ def _use_interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Chunked causal linear attention
+# Chunked causal linear attention (the training mix)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _lin_attn(qf: Array, kf: Array, v: Array, chunk: int, eps: float):
-    n = qf.shape[:-2]
-    l, m = qf.shape[-2:]
-    dv = v.shape[-1]
-    qf2 = qf.reshape(-1, l, m)
-    kf2 = kf.reshape(-1, l, m)
-    v2 = v.reshape(-1, l, dv)
-    out = linear_attention_causal_fwd(qf2, kf2, v2, chunk=chunk, eps=eps,
-                                      interpret=_use_interpret())
-    return out.reshape(*n, l, dv)
+def _operand_dtype(x: Array):
+    """Matmul operands of the causal mix: on the TPU one bf16 pass, what
+    XLA gives an f32 einsum there at its default precision; elsewhere
+    the input's own dtype."""
+    return x.dtype if _use_interpret() else jnp.bfloat16
 
 
-def _lin_attn_fwd(qf, kf, v, chunk, eps):
-    return _lin_attn(qf, kf, v, chunk, eps), (qf, kf, v)
+def train_mix_kernel(use_kernel: bool) -> bool:
+    """Whether the training step's causal PRF mix takes the Pallas pair:
+    always on one TPU device; off the TPU (interpreted) when
+    ``use_kernel`` asks for it. A step over several devices keeps the
+    XLA path, since a Mosaic kernel is not partitioned automatically."""
+    if _use_interpret():
+        return use_kernel
+    return jax.device_count() == 1
 
 
-def _lin_attn_bwd(chunk, eps, res, g):
-    qf, kf, v = res
-    n = qf.shape[:-2]
-    l, m = qf.shape[-2:]
-    dv = v.shape[-1]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _lin_attn(qf: Array, kf: Array, v: Array, eps: float, block: int,
+              dtypes: tuple):
+    return _lin_attn_fwd(qf, kf, v, eps, block, dtypes)[0]
 
-    def f(qf_, kf_, v_):
-        return _ref.linear_attention_causal_ref(
-            qf_.reshape(-1, l, m), kf_.reshape(-1, l, m),
-            v_.reshape(-1, l, dv), eps=eps).reshape(*n, l, dv)
 
-    _, vjp = jax.vjp(f, qf, kf, v)
-    return vjp(g)
+def _lin_attn_fwd(qf, kf, v, eps, block, dtypes):
+    dt = _operand_dtype(qf)
+    q, k = qf.astype(dt), kf.astype(dt)
+    out, den = prf_mix_fwd(q, k, v, eps=eps, block=block,
+                           interpret=_use_interpret())
+    return out, (q, k, v, out, den)
+
+
+def _lin_attn_bwd(eps, block, dtypes, res, g):
+    q, k, v, out, den = res
+    grads = prf_mix_bwd(q, k, v, g, out, den, eps=eps, block=block,
+                        interpret=_use_interpret())
+    return tuple(x.astype(dt) for x, dt in zip(grads, dtypes))
 
 
 _lin_attn.defvjp(_lin_attn_fwd, _lin_attn_bwd)
 
 
 def linear_attention_causal(qf: Array, kf: Array, v: Array, *,
-                            chunk: int = 256, eps: float = 1e-6) -> Array:
-    """Causal PRF attention via the Pallas scan kernel. (..., L, m) x
-    (..., L, dv) -> (..., L, dv); differentiable (oracle-VJP backward)."""
-    return _lin_attn(qf, kf, v, chunk, eps)
+                            eps: float = 1e-6, block: int = 128) -> Array:
+    """Causal PRF attention through the Pallas pair of
+    ``linear_attn_scan`` (forward, and a backward of its own).
+
+    qf: (B, G, H, L, m); kf: (B, G, Hk, L, m) and v: (B, G, Hk, L, dv)
+    with Hk = 1, one key head shared by the H query heads of a group, or
+    Hk = H. Returns (B, G, H, L, dv) in v.dtype. The values and output
+    pass to the kernels token-major, (B, L, heads x dv), the layout of
+    the projections around the mix. ``block`` is the kernels' chunk of
+    positions; the result does not depend on it beyond rounding.
+    """
+    b, g, h, l, m = qf.shape
+    dv = v.shape[-1]
+    if kf.shape[2] != 1:                 # each query head its own group
+        qf, kf, v = (x.reshape(b, g * h, 1, l, x.shape[-1])
+                     for x in (qf, kf, v))
+    ng, hq = qf.shape[1:3]
+    vt = jnp.moveaxis(v[:, :, 0], 1, 2).reshape(b, l, ng * dv)
+    out = _lin_attn(qf, kf[:, :, 0], vt, eps, block,
+                    (qf.dtype, kf.dtype, v.dtype))
+    out = jnp.moveaxis(out.reshape(b, l, ng, hq, dv), 1, 3)
+    return out.reshape(b, g, h, l, dv)
 
 
 def linear_attention_prefill_chunk(qf: Array, kf: Array, v: Array,

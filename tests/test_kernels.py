@@ -4,9 +4,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import linear_attention as la
 from repro.kernels import ops, ref
-from repro.kernels.linear_attn_scan import linear_attention_causal_fwd
+from repro.kernels.linear_attn_scan import prf_mix_fwd
 from repro.kernels.prf_featmap import prf_featmap_fwd
+
+
+def _fwd(qf, kf, v, chunk):
+    """The training forward kernel on (N, L, .) rows: one batch row of
+    N groups of one query head, values and output token-major."""
+    n, l, dv = v.shape
+    out, _ = prf_mix_fwd(qf[None, :, None], kf[None],
+                         jnp.moveaxis(v, 0, 1).reshape(1, l, n * dv),
+                         block=chunk, interpret=True)
+    return jnp.moveaxis(out.reshape(l, n, dv), 0, 1)
 
 
 @pytest.mark.parametrize("n,l,m,dv,chunk", [
@@ -22,8 +33,7 @@ def test_linear_attn_kernel_shapes(n, l, m, dv, chunk):
     qf = jax.random.uniform(kq, (n, l, m))
     kf = jax.random.uniform(kk, (n, l, m))
     v = jax.random.normal(kv, (n, l, dv))
-    out = linear_attention_causal_fwd(qf, kf, v, chunk=chunk,
-                                      interpret=True)
+    out = _fwd(qf, kf, v, chunk)
     expect = ref.linear_attention_causal_ref(qf, kf, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=2e-5)
@@ -36,7 +46,7 @@ def test_linear_attn_kernel_dtypes(dtype):
     qf = jax.random.uniform(kq, (2, 64, 16)).astype(dtype)
     kf = jax.random.uniform(kk, (2, 64, 16)).astype(dtype)
     v = jax.random.normal(kv, (2, 64, 8)).astype(dtype)
-    out = linear_attention_causal_fwd(qf, kf, v, chunk=32, interpret=True)
+    out = _fwd(qf, kf, v, 32)
     expect = ref.linear_attention_causal_ref(qf, kf, v)
     assert out.dtype == dtype
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
@@ -44,23 +54,66 @@ def test_linear_attn_kernel_dtypes(dtype):
                                np.asarray(expect, np.float32), atol=tol)
 
 
+def _group_inputs(b, g, hg, l, m, dv, seed):
+    """Positive features as the model makes them (one key head per
+    group of ``hg`` query heads), values and an output cotangent."""
+    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(seed), 4)
+    qf = jnp.exp(0.5 * jax.random.normal(kq, (b, g, hg, l, m))) * m ** -0.5
+    kf = jnp.exp(0.5 * jax.random.normal(kk, (b, g, 1, l, m))) * m ** -0.5
+    v = jax.random.normal(kv, (b, g, 1, l, dv))
+    ct = jax.random.normal(kg, (b, g, hg, l, dv))
+    return qf, kf, v, ct
+
+
+def _blockwise(qf, kf, v, eps, chunk):
+    return la.linear_attention_causal_blockwise(
+        qf, jnp.broadcast_to(kf, qf.shape),
+        jnp.broadcast_to(v, qf.shape[:-1] + v.shape[-1:]), chunk=chunk,
+        eps=eps)
+
+
 def test_linear_attn_gradients_match_oracle():
-    key = jax.random.PRNGKey(1)
-    kq, kk, kv = jax.random.split(key, 3)
-    qf = jax.random.uniform(kq, (2, 48, 16))
-    kf = jax.random.uniform(kk, (2, 48, 16))
-    v = jax.random.normal(kv, (2, 48, 8))
+    """Gradients of the Pallas pair (its own backward) match autodiff of
+    the blockwise XLA path."""
+    qf, kf, v, _ = _group_inputs(2, 2, 3, 48, 16, 8, seed=1)
 
     def l_kernel(q, k, v_):
-        return jnp.sum(ops.linear_attention_causal(q, k, v_, chunk=16) ** 2)
+        return jnp.sum(ops.linear_attention_causal(q, k, v_, block=16)
+                       ** 2)
 
     def l_ref(q, k, v_):
-        return jnp.sum(ref.linear_attention_causal_ref(q, k, v_) ** 2)
+        return jnp.sum(_blockwise(q, k, v_, 1e-6, 16) ** 2)
 
     g1 = jax.grad(l_kernel, argnums=(0, 1, 2))(qf, kf, v)
     g2 = jax.grad(l_ref, argnums=(0, 1, 2))(qf, kf, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+# Hg query heads per key head, value width, length, kernel chunk T and
+# the denominator's floor: each value of each covered; L % T != 0 pads
+@pytest.mark.parametrize("hg,dv,l,block,eps", [
+    (1, 64, 64, 32, 1e-30),
+    (3, 64, 72, 32, 1e-8),
+    (4, 64, 64, 16, 1e-8),
+    (1, 128, 40, 16, 1e-8),
+    (3, 128, 64, 64, 1e-30),
+    (4, 128, 56, 32, 1e-30),
+])
+def test_prf_mix_matches_blockwise(hg, dv, l, block, eps):
+    """Forward and the gradients for qf, kf and v of the Pallas pair
+    against ``linear_attention_causal_blockwise`` and its ``jax.vjp``."""
+    qf, kf, v, g = _group_inputs(2, 2, hg, l, 32, dv, seed=hg * dv + l)
+    out, vjp = jax.vjp(lambda *a: ops.linear_attention_causal(
+        *a, eps=eps, block=block), qf, kf, v)
+    want, vjp_ref = jax.vjp(lambda *a: _blockwise(*a, eps, 16), qf, kf, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    for name, a, b in zip(("dqf", "dkf", "dv"), vjp(g), vjp_ref(g)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale, atol=2e-5,
+                                   err_msg=name)
 
 
 @pytest.mark.parametrize("n,d,r,m,blk", [
@@ -112,16 +165,31 @@ def test_featmap_gradients():
 
 
 def test_kernel_jit_and_vmap_compose():
-    qf = jax.random.uniform(jax.random.PRNGKey(0), (2, 3, 32, 8))
-    kf = jax.random.uniform(jax.random.PRNGKey(1), (2, 3, 32, 8))
-    v = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 32, 4))
-    out = jax.jit(lambda a, b, c: ops.linear_attention_causal(
-        a, b, c, chunk=16))(qf, kf, v)
-    expect = ref.linear_attention_causal_ref(
-        qf.reshape(6, 32, 8), kf.reshape(6, 32, 8), v.reshape(6, 32, 4)
-    ).reshape(2, 3, 32, 4)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               atol=2e-5)
+    """The Pallas pair under jit and vmap (over a batch of groups of
+    three heads sharing a key head), forward and gradients, against the
+    masked O(L^2) oracle per head."""
+    qf, kf, v, _ = _group_inputs(4, 2, 3, 32, 8, 4, seed=0)
+    qf, kf, v = (x.reshape(2, 2, *x.shape[1:]) for x in (qf, kf, v))
+
+    def mix(q, k, v_):
+        return ops.linear_attention_causal(q, k, v_, block=16)
+
+    def oracle(q, k, v_):
+        q3 = q.reshape(-1, 32, 8)
+        k3 = jnp.broadcast_to(k, q.shape).reshape(-1, 32, 8)
+        v3 = jnp.broadcast_to(v_, q.shape[:-1] + (4,)).reshape(-1, 32, 4)
+        return ref.linear_attention_causal_ref(q3, k3, v3).reshape(
+            *q.shape[:-1], 4)
+
+    out = jax.jit(jax.vmap(mix))(qf, kf, v)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(oracle(qf, kf, v)), atol=2e-5)
+    g1 = jax.jit(jax.grad(lambda *a: jnp.sum(jax.vmap(mix)(*a) ** 2),
+                          argnums=(0, 1, 2)))(qf, kf, v)
+    g2 = jax.grad(lambda *a: jnp.sum(oracle(*a) ** 2),
+                  argnums=(0, 1, 2))(qf, kf, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
 def test_rglru_ref_matches_manual_loop():
@@ -272,7 +340,6 @@ def test_prf_decode_step_ops_wrapper_shapes():
 
 from repro.kernels.linear_attn_scan import (  # noqa: E402
     linear_attention_causal_carry_fwd)
-from repro.core import linear_attention as la  # noqa: E402
 
 
 def _carry_inputs(n, l, m, dv, seed=0):
@@ -302,14 +369,16 @@ def test_carry_kernel_matches_oracle(n, l, m, dv, chunk):
 
 
 def test_carry_kernel_zero_state_matches_fresh_kernel():
-    """Seeding with zeros is exactly the fresh-sequence kernel."""
+    """Seeding with zeros is the fresh-sequence (training) kernel; the
+    two sum the denominator in different orders."""
     qf, kf, v, _, _ = _carry_inputs(2, 48, 16, 8, seed=3)
     s0 = jnp.zeros((2, 16, 8))
     z0 = jnp.zeros((2, 16))
     out, _, _ = linear_attention_causal_carry_fwd(
         qf, kf, v, s0, z0, chunk=16, interpret=True)
-    fresh = linear_attention_causal_fwd(qf, kf, v, chunk=16, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(fresh))
+    fresh = _fwd(qf, kf, v, 16)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(fresh),
+                               atol=2e-5)
 
 
 def test_carry_kernel_chained_chunks_match_single_pass():

@@ -13,6 +13,7 @@ one process may hold the TPU library, and the test workers all import
 this file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,13 +23,15 @@ from jax.sharding import SingleDeviceSharding
 from repro import configs as cfgs
 from repro.kernels import ops
 from repro.kernels.linear_attn_scan import (
-    linear_attention_causal_carry_fwd, linear_attention_causal_fwd)
+    linear_attention_causal_carry_fwd, prf_mix_fwd)
 from repro.kernels.prf_decode_step import prf_decode_step_fwd
 from repro.kernels.prf_featmap import prf_featmap_fwd
 from repro.kernels.prf_fused_decode import prf_fused_decode_fwd
 from repro.kernels.prf_fused_prefill import prf_fused_prefill_fwd
 from repro.kernels.wkv6_scan import wkv6_fwd
+from repro.launch import steps as steps_lib
 from repro.models import lm
+from repro.optim import AdamWConfig, adamw_init
 
 # smollm-135m attention geometry (configs/smollm_135m.py): 9 query heads
 # in 3 KV groups, d_head 64, darkformer m=256 features of rank r = d.
@@ -90,8 +93,73 @@ def test_prf_fused_prefill_compiles(one_chip, dark):
 
 
 def test_linear_attention_causal_compiles(one_chip):
-    _compile(linear_attention_causal_fwd, one_chip, ((N, L, M), F32),
-             ((N, L, M), F32), ((N, L, D), F32))
+    _compile(prf_mix_fwd, one_chip, ((ROWS, G, HG, L, M), BF16),
+             ((ROWS, G, L, M), BF16), ((ROWS, L, G * D), BF16))
+
+
+# the training mix's Pallas pair at the widths that run it: smollm-135m
+# (3 heads a group, d_head 64) and granite-8b (4 heads a group, d_head
+# 128), m = 256, 2048-token rows; both kernels of the backward as well
+@pytest.mark.parametrize("hg,dv", [(HG, D), (4, 128)],
+                         ids=["smollm", "granite"])
+def test_prf_mix_pair_compiles(one_chip, monkeypatch, hg, dv):
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+
+    def f(q, k, v, g):
+        out, vjp = jax.vjp(lambda *a: ops.linear_attention_causal(
+            *a, eps=1e-30), q, k, v)
+        return out, vjp(g)
+    n, l = 2, 2048
+    hlo = _compile(f, one_chip, ((n, G, hg, l, M), F32),
+                   ((n, G, 1, l, M), F32), ((n, G, 1, l, dv), BF16),
+                   ((n, G, hg, l, dv), BF16)).as_text()
+    assert {name for name, _ in _kernel_scopes(hlo)} == set(KERNELS)
+
+
+KERNELS = ("prf_mix_fwd", "prf_mix_bwd_dq", "prf_mix_bwd_dkv")
+
+
+def _kernel_scopes(hlo: str) -> set:
+    """(kernel, enclosing scope) of each Pallas call in ``hlo``, from
+    its op_name path: ".../prf_mix/transpose(jvp(prf_mix_bwd_dq))/..."
+    names the kernel and the scope just outside it."""
+    found = set()
+    for path in re.findall(r'custom_call_target="tpu_custom_call".*?'
+                           r'op_name="([^"]*)"', hlo):
+        parts = [re.sub(r"^(?:[\w\-]+\()*([^()]*)\)*$", r"\1", p)
+                 for p in path.split("/")]
+        for i, part in enumerate(parts):
+            if part in KERNELS:
+                found.add((part, parts[i - 1] if i else None))
+    return found
+
+
+def test_train_step_takes_the_prf_mix_kernels(one_chip, monkeypatch):
+    """The smollm-135m training step (value_and_grad through the remat'd
+    layer scan, two layers at full width, 2048-token rows) runs its
+    causal PRF mix in the Pallas pair, forward and backward, under the
+    ``prf_mix`` scope, and holds no (L, L) array."""
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    cfg = dataclasses.replace(cfgs.get_config("smollm-135m"), n_layers=2)
+    l = 2048
+    opt_cfg = AdamWConfig()
+    step = steps_lib.make_train_step(cfg, opt_cfg, lambda s: 1e-4)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+    params = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0),
+                                                   cfg))
+    opt = jax.eval_shape(lambda: adamw_init(params, opt_cfg))
+    batch = {k: jax.ShapeDtypeStruct((1, l), jnp.int32, sharding=one_chip)
+             for k in ("tokens", "labels")}
+    hlo = jax.jit(step).lower(
+        place(params), place(opt), batch,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert _kernel_scopes(hlo) == {(name, "prf_mix") for name in KERNELS}
+    assert not re.search(rf"\[(\d+,)*{l},{l}\]", hlo)
 
 
 def test_linear_attention_carry_compiles(one_chip):
