@@ -39,7 +39,7 @@ def bench_cfg(kernel: str = "darkformer", m: int = 16,
     return ModelConfig(
         name=f"bench-{kernel}", n_layers=4, d_model=64, n_heads=4, n_kv=1,
         d_head=16, d_ff=192, vocab=VOCAB, mlp_kind="geglu",
-        embed_scale=True, tie_embeddings=True, remat="none",
+        embedding_multiplier=64 ** 0.5, tie_embeddings=True, remat="none",
         scan_layers=scan,
         attn=FeatureConfig(kind=kernel, num_features=m, orthogonal=True))
 

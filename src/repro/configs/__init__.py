@@ -33,12 +33,26 @@ def _module(name: str):
 
 
 def get_config(name: str, reduced: bool = False, **overrides) -> ModelConfig:
+    """The architecture's config with ``overrides`` replaced. An override
+    of a nested config field takes a dict of that config's fields (as a
+    configuration file gives it, lists for tuples): ``moe={"held": [0,
+    20]}``."""
     mod = _module(name)
     cfg = mod.reduced() if reduced else mod.config()
-    if overrides:
-        import dataclasses
-        cfg = dataclasses.replace(cfg, **overrides)
-    return cfg
+    return _replace(cfg, overrides)
+
+
+def _replace(cfg, overrides: dict):
+    import dataclasses
+    new = {}
+    for key, val in overrides.items():
+        cur = getattr(cfg, key)
+        if isinstance(val, dict) and dataclasses.is_dataclass(cur):
+            val = _replace(cur, val)
+        elif isinstance(val, list):
+            val = tuple(val)
+        new[key] = val
+    return dataclasses.replace(cfg, **new) if new else cfg
 
 
 def cell_supported(cfg: ModelConfig, shape_name: str) -> tuple[bool, str]:

@@ -13,8 +13,8 @@ def config() -> ModelConfig:
         n_kv=1, d_head=256, d_ff=7680, vocab=256_000,
         block_pattern=("rec", "rec", "local"), window=2048,
         mlp_kind="geglu", attn=DEFAULT_ATTN, rope_theta=10_000.0,
-        d_rnn=2560, embed_scale=True, tie_embeddings=True,
-        logit_softcap=30.0, dtype="bfloat16")
+        d_rnn=2560, embedding_multiplier=2560 ** 0.5,
+        tie_embeddings=True, logit_softcap=30.0, dtype="bfloat16")
 
 
 def reduced() -> ModelConfig:
@@ -24,4 +24,5 @@ def reduced() -> ModelConfig:
         block_pattern=("rec", "rec", "local"), window=16,
         mlp_kind="geglu", attn=DEFAULT_ATTN.__class__(
             kind="darkformer", num_features=32),
-        d_rnn=64, embed_scale=True, tie_embeddings=True, remat="none")
+        d_rnn=64, embedding_multiplier=64 ** 0.5, tie_embeddings=True,
+        remat="none")

@@ -44,10 +44,12 @@ Array = jax.Array
 PRF_KINDS = fm.PRF_KINDS
 
 
-def _scale_qk(q: Array, k: Array) -> tuple[Array, Array]:
-    """Absorb the 1/sqrt(d) softmax temperature symmetrically (paper fn. 2)."""
+def _scale_qk(q: Array, k: Array, multiplier: Optional[float] = None
+              ) -> tuple[Array, Array]:
+    """Absorb the 1/sqrt(d) softmax temperature symmetrically (paper fn. 2),
+    or a model's own logit scale ``multiplier`` in its place."""
     d = q.shape[-1]
-    s = d ** -0.25
+    s = d ** -0.25 if multiplier is None else multiplier ** 0.5
     return q * s, k * s
 
 
@@ -135,7 +137,8 @@ def rf_attention(q: Array, k: Array, v: Array, fparams: Optional[dict],
                  cfg: fm.FeatureConfig, *, causal: bool = True,
                  window: Optional[int] = None, chunk: int = 256,
                  use_kernel: bool = False,
-                 baseline_key: Optional[Array] = None) -> Array:
+                 baseline_key: Optional[Array] = None,
+                 attention_multiplier: Optional[float] = None) -> Array:
     """Training-time attention. Returns (B, G, Hg, L, dv).
 
     The causal PRF mix takes the Pallas pair (KV groups unbroadcast, a
@@ -146,7 +149,7 @@ def rf_attention(q: Array, k: Array, v: Array, fparams: Optional[dict],
     b, g, hg, l, _ = q.shape
     dv = v.shape[-1]
     if cfg.kind == "exact":
-        qs, ks = _scale_qk(q, k)
+        qs, ks = _scale_qk(q, k, attention_multiplier)
         return la.exact_attention(qs, ks, v, causal=causal, window=window)
     if cfg.kind == "constant":
         out = la.constant_attention(v, causal=causal)
@@ -157,7 +160,7 @@ def rf_attention(q: Array, k: Array, v: Array, fparams: Optional[dict],
         return jnp.broadcast_to(out, (b, g, hg, l, dv))
 
     with jax.named_scope(scopes.PRF_FEATURES):
-        qs, ks = _scale_qk(q, k)
+        qs, ks = _scale_qk(q, k, attention_multiplier)
     qf, kf, _ = _qk_feature_pair(qs, ks, fparams, cfg)
     with jax.named_scope(scopes.PRF_MIX):
         if causal and kops.train_mix_kernel(use_kernel):
@@ -327,7 +330,8 @@ def rf_attention_prefill(q, k, v, fparams, cfg: fm.FeatureConfig, *,
                          use_kernel: bool = False,
                          state: Optional[AttnServeState] = None,
                          valid_len: Optional[Array] = None,
-                         proj: Optional[dict] = None):
+                         proj: Optional[dict] = None,
+                         attention_multiplier: Optional[float] = None):
     """Causal pass over a prompt (chunk) + advanced serving state.
 
     ``state=None`` is the legacy whole-prompt entry point: the serving
@@ -362,7 +366,7 @@ def rf_attention_prefill(q, k, v, fparams, cfg: fm.FeatureConfig, *,
         raise ValueError("valid_len requires an incoming serve state "
                          "(ragged rows only arise in resumed chunks)")
     if cfg.kind == "exact":
-        qs, ks = _scale_qk(q, k)
+        qs, ks = _scale_qk(q, k, attention_multiplier)
         if state is not None:
             if state.table is not None:
                 return _exact_paged_append_attend(qs, ks, v, state, window,
@@ -378,7 +382,7 @@ def rf_attention_prefill(q, k, v, fparams, cfg: fm.FeatureConfig, *,
                                length=jnp.full((), l, jnp.int32))
         return out, state
 
-    qs, ks = _scale_qk(q, k)
+    qs, ks = _scale_qk(q, k, attention_multiplier)
     if state is None:
         qf, kf, kc = _qk_feature_pair(qs, ks, fparams, cfg)
         kfb = jnp.broadcast_to(kf, (b, g, hg, l, cfg.num_features))
@@ -448,7 +452,8 @@ def rf_attention_decode(q, k, v, state: AttnServeState, fparams,
                         cfg: fm.FeatureConfig, *,
                         window: Optional[int] = None,
                         use_kernel: bool = False,
-                        proj: Optional[dict] = None):
+                        proj: Optional[dict] = None,
+                        attention_multiplier: Optional[float] = None):
     """One-token decode. q: (B,G,Hg,1,d); k,v: (B,G,1,1,d).
 
     ``state.length`` (exact) may be () for lock-step batches or (B,) for
@@ -464,13 +469,13 @@ def rf_attention_decode(q, k, v, state: AttnServeState, fparams,
     b, g, hg, _, _ = q.shape
     dv = v.shape[-1]
     if cfg.kind == "exact":
-        qs, ks = _scale_qk(q, k)
+        qs, ks = _scale_qk(q, k, attention_multiplier)
         if state.table is not None:
             return _exact_paged_append_attend(qs, ks, v, state, window,
                                               v.dtype)
         return _exact_decode(qs, ks, v, state, window, v.dtype)
 
-    qs, ks = _scale_qk(q, k)
+    qs, ks = _scale_qk(q, k, attention_multiplier)
     if use_kernel and proj is not None and cfg.kind in PRF_KINDS:
         out, s, z, c = kops.fused_prf_decode(
             qs[..., 0, :], ks[:, :, 0, 0, :], v[:, :, 0, 0, :],

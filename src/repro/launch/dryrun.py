@@ -95,19 +95,13 @@ def hbm_traffic_bytes(cost: dict) -> float:
 
 def build_cell(arch: str, shape_name: str, mesh, kernel: str,
                remat: str = "dots", cfg=None, preset: str = "2d",
-               shard_features: bool = False, pin_moe: bool = False):
+               shard_features: bool = False):
     """Returns (lower_fn, meta) for the cell; lower_fn() -> jax.Lowered."""
     if cfg is None:
         cfg = cfgs.get_config(arch)
         if kernel:
             cfg = cfgs.darkify(cfg, kernel, cfg.attn.num_features)
         cfg = dataclasses.replace(cfg, remat=remat)
-    if pin_moe and cfg.moe is not None:
-        from repro.parallel.sharding import dp_axes
-        eax = ("model" if cfg.moe.num_experts % mesh.shape["model"] == 0
-               else None)
-        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-            cfg.moe, dispatch_spec=(dp_axes(mesh), eax)))
     ok, why = cfgs.cell_supported(cfg, shape_name)
     if not ok:
         return None, {"skipped": why}
@@ -257,7 +251,7 @@ def run_cost_probe(arch: str, shape_name: str, kernel: str, outdir: str,
                    force: bool = False, remat: str = "dots",
                    tag: str = "", preset: str = "2d",
                    shard_features: bool = False,
-                   features: int = 0, pin_moe: bool = False) -> dict:
+                   features: int = 0) -> dict:
     """Exact per-device cost extrapolation for scanned stacks.
 
     XLA's HloCostAnalysis counts a while-loop body ONCE (verified: a scan
@@ -293,20 +287,12 @@ def run_cost_probe(arch: str, shape_name: str, kernel: str, outdir: str,
         plen = len(cfg_full.block_pattern)
         rem = cfg_full.n_rem
         probes = []
-        if pin_moe and cfg_full.moe is not None:
-            from repro.parallel.sharding import dp_axes
-            eax = ("model" if cfg_full.moe.num_experts %
-                   mesh.shape["model"] == 0 else None)
-            cfg_full = dataclasses.replace(
-                cfg_full, moe=dataclasses.replace(
-                    cfg_full.moe, dispatch_spec=(dp_axes(mesh), eax)))
         for units in (1, 2):
             cfg_p = dataclasses.replace(
                 cfg_full, n_layers=plen * units + rem, scan_layers=False)
             lower_fn, meta = build_cell(arch, shape_name, mesh, kernel,
                                         remat, cfg=cfg_p, preset=preset,
-                                        shard_features=shard_features,
-                                        pin_moe=pin_moe)
+                                        shard_features=shard_features)
             if lower_fn is None:
                 rec["status"] = "skipped"
                 rec["skipped"] = meta.get("skipped", "")
@@ -354,8 +340,6 @@ def main():
     ap.add_argument("--shard-features", action="store_true")
     ap.add_argument("--features", type=int, default=0,
                     help="override PRF feature count m (probe only)")
-    ap.add_argument("--pin-moe", action="store_true",
-                    help="pin MoE dispatch buffers' sharding (perf exp)")
     ap.add_argument("--tag", default="")
     ap.add_argument("--outdir", default="experiments/dryrun")
     args = ap.parse_args()
@@ -372,7 +356,7 @@ def main():
                 rec = run_cost_probe(arch, shape, args.kernel, args.outdir,
                                      args.force, args.remat, args.tag,
                                      args.preset, args.shard_features,
-                                     args.features, args.pin_moe)
+                                     args.features)
                 status = rec.get("status")
                 n_ok += status == "ok"
                 n_skip += status == "skipped"

@@ -73,7 +73,8 @@ def attn_apply(params: dict, x: Array, cfg: fm.FeatureConfig, *,
                qk_norm: bool = False, rope_theta: float = 10000.0,
                positions: Optional[Array] = None,
                use_kernel: bool = False,
-               baseline_key: Optional[Array] = None) -> Array:
+               baseline_key: Optional[Array] = None,
+               attention_multiplier: Optional[float] = None) -> Array:
     l = x.shape[1]
     if positions is None:
         positions = jnp.arange(l)
@@ -81,14 +82,16 @@ def attn_apply(params: dict, x: Array, cfg: fm.FeatureConfig, *,
                        positions, rope_theta)
     out = rfa.rf_attention(q, k, v, params.get("feat"), cfg, causal=causal,
                            window=window, use_kernel=use_kernel,
-                           baseline_key=baseline_key)
+                           baseline_key=baseline_key,
+                           attention_multiplier=attention_multiplier)
     return _merge_heads(out, params)
 
 
 def attn_prefill(params, x, cfg, *, n_heads, n_kv, d_head,
                  window=None, qk_norm=False, rope_theta=10000.0,
                  max_len=None, use_kernel=False, state=None,
-                 position=None, valid_len=None, proj=None):
+                 position=None, valid_len=None, proj=None,
+                 attention_multiplier=None):
     """Prefill one prompt chunk. ``state=None`` + ``position=None`` is the
     legacy whole-prompt call; with an incoming serve ``state`` and a chunk
     start ``position`` (() int32, or (B,) per-slot starts) the pass
@@ -112,13 +115,14 @@ def attn_prefill(params, x, cfg, *, n_heads, n_kv, d_head,
     out, state = rfa.rf_attention_prefill(
         q, k, v, params.get("feat"), cfg, window=window,
         max_len=max_len, use_kernel=use_kernel, state=state,
-        valid_len=valid_len, proj=proj)
+        valid_len=valid_len, proj=proj,
+        attention_multiplier=attention_multiplier)
     return _merge_heads(out, params), state
 
 
 def attn_decode(params, x, state, cfg, *, n_heads, n_kv, d_head,
                 position, window=None, qk_norm=False, rope_theta=10000.0,
-                use_kernel=False, proj=None):
+                use_kernel=False, proj=None, attention_multiplier=None):
     """x: (B, 1, d_model); position: () int32 current index, or (B,)
     int32 per-slot positions (continuous batching — each slot RoPE-rotates
     by its own sequence position). ``proj`` is the block's precomposed
@@ -134,7 +138,9 @@ def attn_decode(params, x, state, cfg, *, n_heads, n_kv, d_head,
                                          params.get("feat"), cfg,
                                          window=window,
                                          use_kernel=use_kernel,
-                                         proj=proj)
+                                         proj=proj,
+                                         attention_multiplier=(
+                                             attention_multiplier))
     return _merge_heads(out, params), state
 
 

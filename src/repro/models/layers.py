@@ -11,6 +11,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from repro.core import scopes
 
 Array = jax.Array
 
@@ -108,90 +111,125 @@ def mlp_apply(params: dict, x: Array, kind: str) -> Array:
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts (top-k, capacity-based einsum dispatch — GShard style)
+# Mixture of Experts (top-k, dropless: sorted dispatch, grouped matmuls)
 # ---------------------------------------------------------------------------
+
+# what the "dots" remat policy saves of an expert layer by name (it
+# matches dot_general, and a grouped matmul is not one): the routing's
+# permutation, the dispatched rows, the grouped matmuls' outputs and
+# the rows gathered back, so the backward pass neither sorts, gathers
+# nor multiplies again
+MOE_SAVED = "moe_saved"
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     num_experts: int
     top_k: int
     d_ff: int                    # per-expert hidden
-    capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
-    # Optional (dp_axes, expert_axis) to pin the dispatch buffers: the
-    # (B,E,C,d) scatter/gather buffers get batch on dp_axes and the expert
-    # dim on expert_axis ("model" under EP, None under TP-expert
-    # fallback). Prevents XLA SPMD from re-sharding them across 'model'
-    # (shows up as huge all-reduces in the collective roofline term).
-    # Requires an ambient mesh (jax.set_mesh) at trace time.
-    dispatch_spec: Optional[tuple] = None
+    # the contiguous experts [start, stop) this device holds; None holds
+    # all of them. The router always scores all ``num_experts``.
+    held: Optional[tuple] = None
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return tuple(self.held) if self.held else (0, self.num_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
 
 
 def moe_init(key, d_model: int, cfg: MoEConfig, dtype=jnp.float32) -> dict:
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    e, f = cfg.num_experts, cfg.d_ff
+    e, f = cfg.n_held, cfg.d_ff
     return {
-        "router": trunc_normal(k1, (d_model, e), 1.0, jnp.float32),
+        "router": trunc_normal(k1, (d_model, cfg.num_experts), 1.0,
+                               jnp.float32),
         "w_gate": trunc_normal(k2, (e, d_model, f), 1.0, dtype),
         "w_up": trunc_normal(k3, (e, d_model, f), 1.0, dtype),
         "w_out": trunc_normal(k4, (e, f, d_model), 1.0, dtype),
     }
 
 
-def moe_apply(params: dict, x: Array, cfg: MoEConfig
-              ) -> tuple[Array, Array]:
-    """x: (B, L, d) -> (out, aux_loss). Capacity-dropped top-k routing.
+@jax.custom_vjp
+def _permute_rows(x, order, inv):
+    """``x[order]`` for a permutation ``order`` with inverse ``inv``;
+    the backward pass is the gather ``ct[inv]``, not a scatter-add."""
+    return x[order]
 
-    Dispatch/combine are one-hot einsums over a (B, E, C) capacity buffer so
-    XLA SPMD can turn the expert axis into all-to-all under EP sharding.
+
+def _permute_rows_fwd(x, order, inv):
+    return x[order], (order, inv)
+
+
+def _permute_rows_bwd(res, ct):
+    order, inv = res
+    return ct[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def moe_apply(params: dict, x: Array, cfg: MoEConfig
+              ) -> tuple[Array, Array, dict]:
+    """x: (B, L, d) -> (out, aux_loss, counters). Dropless top-k routing
+    over the experts this device holds.
+
+    The router scores all experts in float32 at the highest precision;
+    each token takes its top k, gated by a softmax over their k logits.
+    The (token, choice) pairs are sorted by expert, those routed to held
+    experts first, and run through grouped matmuls (``lax.ragged_dot``)
+    over the held experts' group sizes, so every such pair is computed,
+    whatever the skew. Pairs routed to experts held elsewhere add
+    nothing here: their rows, which no group covers, are masked on the
+    way in and out (the grouped matmuls leave such rows undefined).
+    ``counters``: ``load`` (held,) int32, the pairs each held expert
+    took; ``away`` () int32, the pairs routed to experts not held.
     """
     b, l, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
-    cap = max(1, int(cfg.capacity_factor * k * l / e))
-    logits = (x.astype(jnp.float32) @ params["router"])       # (B, L, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, idx = jax.lax.top_k(probs, k)                  # (B, L, K)
-    gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True) + 1e-9)
-
-    # Load-balancing aux loss (Switch): E * sum_e f_e * p_e.
-    me = jnp.mean(probs, axis=(0, 1))                         # (E,)
-    onehot_top1 = jax.nn.one_hot(idx[..., 0], e)
-    ce = jnp.mean(onehot_top1, axis=(0, 1))
-    aux = cfg.aux_loss_weight * e * jnp.sum(me * ce)
-
-    # Position of each (token, k) within its expert's capacity buffer
-    # (GShard semantics: capacity group = batch row). Dispatch/combine are
-    # scatter/gather (O(B L K d)) rather than one-hot einsums (O(B L E C d))
-    # so neither compute nor memory scales with E*C; under EP sharding the
-    # scatter across the expert axis lowers to the MoE all-to-all.
-    sel = jax.nn.one_hot(idx, e, dtype=jnp.int32)             # (B, L, K, E)
-    flat = sel.reshape(b, l * k, e)
-    pos_e = jnp.cumsum(flat, axis=1) - 1                      # (B, L*K, E)
-    pos = jnp.take_along_axis(
-        pos_e.reshape(b, l, k, e), idx[..., None], axis=-1)[..., 0]
-    in_cap = pos < cap                                        # (B, L, K)
-    pos_c = jnp.clip(pos, 0, cap - 1)
-    b_idx = jnp.arange(b)[:, None, None]
-    upd = (x[:, :, None, :] * in_cap[..., None].astype(x.dtype))
-    xin = jnp.zeros((b, e, cap, d), x.dtype).at[
-        b_idx, idx, pos_c].add(upd)                           # (B, E, C, d)
-
-    def _pin(t, expert_axis):
-        if cfg.dispatch_spec is None:
-            return t
-        from jax.sharding import PartitionSpec as P
-        dp, eax = cfg.dispatch_spec
-        axes = [tuple(dp)] + [None] * (t.ndim - 1)
-        if expert_axis:
-            axes[1] = eax
-        return jax.lax.with_sharding_constraint(t, P(*axes))
-
-    xin = _pin(xin, True)
-    h = jnp.einsum("becd,edf->becf", xin, params["w_gate"])
-    hu = jnp.einsum("becd,edf->becf", xin, params["w_up"])
-    h = jax.nn.silu(h) * hu
-    xout = _pin(jnp.einsum("becf,efd->becd", h, params["w_out"]), True)
-    gathered = _pin(xout[b_idx, idx, pos_c], False)           # (B, L, K, d)
-    gates = (gate_vals * in_cap.astype(jnp.float32)).astype(x.dtype)
-    out = jnp.einsum("blkd,blk->bld", gathered, gates)
-    return out, aux
+    lo, hi = cfg.held_range
+    n_held, t = hi - lo, b * l
+    xt = x.reshape(t, d)
+    with jax.named_scope(scopes.MOE_ROUTER):
+        logits = jnp.dot(xt.astype(jnp.float32), params["router"],
+                         precision=jax.lax.Precision.HIGHEST)   # (T, E)
+        top, idx = jax.lax.top_k(logits, k)                     # (T, K)
+        gates = jax.nn.softmax(top, axis=-1)
+        # load-balancing loss (Switch): E * sum_e f_e * p_e
+        me = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=0)
+        ce = jnp.mean(jax.nn.one_hot(idx[:, 0], e), axis=0)
+        aux = cfg.aux_loss_weight * e * jnp.sum(me * ce)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        local = idx.reshape(t * k) - lo
+        held = (local >= 0) & (local < n_held)
+        key = jnp.where(held, local, n_held)       # away pairs sort last
+        order = jnp.argsort(key, stable=True)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype))
+        load = jnp.sum(jax.nn.one_hot(key, n_held, dtype=jnp.int32), 0)
+        order, inv, load = (checkpoint_name(a, MOE_SAVED)
+                            for a in (order, inv, load))
+        held_rows = (jnp.arange(t * k) < jnp.sum(load))[:, None]
+        xs = checkpoint_name(jnp.where(held_rows, _permute_rows(
+            jnp.repeat(xt, k, axis=0), order, inv), 0), MOE_SAVED)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        hg = checkpoint_name(jax.lax.ragged_dot(xs, params["w_gate"], load),
+                             MOE_SAVED)
+        hu = checkpoint_name(jax.lax.ragged_dot(xs, params["w_up"], load),
+                             MOE_SAVED)
+    h = jax.nn.silu(hg) * hu
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        ys = checkpoint_name(jax.lax.ragged_dot(h, params["w_out"], load),
+                             MOE_SAVED)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        # back to each token's k choices, then the gated sum
+        ys = jnp.where(held_rows, ys, 0)
+        y = checkpoint_name(_permute_rows(ys, inv, order), MOE_SAVED
+                            ).reshape(t, k, d)
+        out = jnp.einsum("tkd,tk->td", y, gates.astype(y.dtype))
+    counters = {"load": load, "away": t * k - jnp.sum(load)}
+    return out.reshape(b, l, d), aux, counters

@@ -30,10 +30,15 @@ from repro.models import recurrent as rec
 
 Array = jax.Array
 
+_CP = jax.checkpoint_policies
 REMAT_POLICIES = {
     "none": None,
-    "full": "nothing_saveable",
-    "dots": "dots_with_no_batch_dims_saveable",
+    "full": _CP.nothing_saveable,
+    # matmuls without batch dimensions, and what the expert layer names
+    # (its grouped matmuls are not dot_generals)
+    "dots": _CP.save_from_both_policies(
+        _CP.dots_with_no_batch_dims_saveable,
+        _CP.save_only_these_names(ll.MOE_SAVED)),
 }
 
 
@@ -59,7 +64,6 @@ class ModelConfig:
     modality: str = "text"                # text|audio|vlm
     norm_kind: str = "rmsnorm"
     d_rnn: int = 0                        # rec blocks; 0 -> d_model
-    embed_scale: bool = False             # gemma-style sqrt(d) embed scale
     logit_softcap: float = 0.0
     num_patches: int = 256                # vlm prefix length
     dtype: str = "float32"                # param/activation dtype
@@ -67,11 +71,19 @@ class ModelConfig:
     scan_layers: bool = True
     use_kernel: bool = False              # pallas linear-attention path
     z_loss: float = 1e-4
+    # Scalar multipliers; None emits no op. The embedding is scaled by
+    # the first (Gemma's sqrt(d_model), Granite's 12), each residual
+    # branch by the second; under PRF attention q and k are each scaled
+    # by the square root of the third (the logits' scale, in place of
+    # 1/sqrt(d)); the logits are divided by the last.
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    attention_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
     # Per-arch sharding-rule overrides: ((path-regex, partition-spec-tuple),
     # ...) applied before the global rules in repro.parallel.sharding.
     # Sharding is geometry-dependent; archs whose dims interact badly with
-    # the global rules pin their empirically-best layout here (see
-    # EXPERIMENTS.md §Perf, granite-moe iterations).
+    # the global rules pin their layout here.
     sharding_overrides: tuple = ()
 
     @property
@@ -175,14 +187,15 @@ def _apply_block(params, x, cfg: ModelConfig, kind: str, *,
     ``cfg.use_kernel`` (prefill: ``prf_fused_prefill``; decode:
     ``prf_fused_decode``).
     """
-    aux = jnp.zeros((), jnp.float32)
+    aux = zero_aux(cfg)
     # the attention's pre-norm is counted with its projections
     with (jax.named_scope(scopes.ATTN_IN) if kind in ("attn", "local")
           else contextlib.nullcontext()):
         h = ll.apply_norm(cfg.norm_kind, params["ln1"], x)
     new_state = state
     common = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv, d_head=cfg.head_dim,
-                  qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta)
+                  qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
+                  attention_multiplier=cfg.attention_multiplier)
     window = cfg.window if kind == "local" else None
     if kind in ("attn", "local"):
         if mode == "train":
@@ -203,7 +216,7 @@ def _apply_block(params, x, cfg: ModelConfig, kind: str, *,
                 window=window, use_kernel=cfg.use_kernel, proj=proj,
                 **common)
         with jax.named_scope(scopes.ATTN_OUT):
-            x = x + mix
+            x = _residual(x, mix, cfg)
         x, aux = _ffn(params["ln2"], params["ffn"], x, aux, cfg)
     elif kind == "rec":
         if mode == "train":
@@ -211,38 +224,64 @@ def _apply_block(params, x, cfg: ModelConfig, kind: str, *,
         else:                       # prefill chunk / decode: carry state
             mix, new_state = rec.rglru_apply(params["rec"], h, state,
                                              valid_len=valid_len)
-        x = x + mix
+        x = _residual(x, mix, cfg)
         x, aux = _ffn(params["ln2"], params["ffn"], x, aux, cfg)
     elif kind == "rwkv":
         if mode == "train":
             mix, _ = rec.rwkv6_apply(params["tmix"], h, cfg.n_heads, None)
-            x = x + mix
+            x = _residual(x, mix, cfg)
             h2 = ll.apply_norm(cfg.norm_kind, params["ln2"], x)
             f, _ = rec.rwkv6_channel_mix(params["cmix"], h2, None)
-            x = x + f
+            x = _residual(x, f, cfg)
         else:                       # prefill chunk / decode: carry state
             tstate, cshift = state
             mix, tstate = rec.rwkv6_apply(params["tmix"], h, cfg.n_heads,
                                           tstate, valid_len=valid_len)
-            x = x + mix
+            x = _residual(x, mix, cfg)
             h2 = ll.apply_norm(cfg.norm_kind, params["ln2"], x)
             f, cshift = rec.rwkv6_channel_mix(params["cmix"], h2, cshift,
                                               valid_len=valid_len)
-            x = x + f
+            x = _residual(x, f, cfg)
             new_state = (tstate, cshift)
     return x, aux, new_state
 
 
+def zero_aux(cfg: ModelConfig) -> dict:
+    """A block's auxiliaries, zero: the loss it adds (``loss``) and, with
+    experts, the routing counters summed over layers (``moe_load``, the
+    (token, choice) pairs each held expert took; ``moe_away``, the pairs
+    routed to experts held elsewhere)."""
+    aux = {"loss": jnp.zeros((), jnp.float32)}
+    if cfg.moe:
+        aux["moe_load"] = jnp.zeros((cfg.moe.n_held,), jnp.int32)
+        aux["moe_away"] = jnp.zeros((), jnp.int32)
+    return aux
+
+
+def _add_aux(a: dict, b: dict) -> dict:
+    return jax.tree_util.tree_map(jnp.add, a, b)
+
+
+def _residual(x, branch, cfg: ModelConfig):
+    if cfg.residual_multiplier is not None:
+        # in float32: the multiplier itself is not a bfloat16 number
+        branch = (branch.astype(jnp.float32) * cfg.residual_multiplier
+                  ).astype(branch.dtype)
+    return x + branch
+
+
 def _ffn(norm, ffn, x, aux, cfg: ModelConfig):
     """Pre-norm FFN (dense or experts) plus its residual. Returns (x,
-    aux), with the experts' load-balancing loss in place of ``aux``."""
+    aux), with the experts' loss and counters in place of ``aux``'s."""
     with jax.named_scope(scopes.MLP):
         h = ll.apply_norm(cfg.norm_kind, norm, x)
         if cfg.moe:
-            f, aux = ll.moe_apply(ffn, h, cfg.moe)
+            f, loss, counters = ll.moe_apply(ffn, h, cfg.moe)
+            aux = {"loss": loss, "moe_load": counters["load"],
+                   "moe_away": counters["away"]}
         else:
             f = ll.mlp_apply(ffn, h, cfg.mlp_kind)
-        return x + f, aux
+        return _residual(x, f, cfg), aux
 
 
 def _embed_inputs(params, cfg: ModelConfig, batch: dict) -> Array:
@@ -254,13 +293,17 @@ def _embed_inputs(params, cfg: ModelConfig, batch: dict) -> Array:
                 me = params["mask_embed"].astype(dt)
                 x = jnp.where(batch["mask"][..., None], me[None, None], x)
             return x
-        tok = params["embed"][batch["tokens"]]
-        if cfg.embed_scale:
-            tok = tok * jnp.asarray(cfg.d_model ** 0.5, dt)
+        tok = _scale_embedding(params["embed"][batch["tokens"]], cfg)
         if cfg.modality == "vlm":
             patches = batch["patch_embeds"].astype(dt)
             return jnp.concatenate([patches, tok.astype(dt)], axis=1)
         return tok.astype(dt)
+
+
+def _scale_embedding(tok: Array, cfg: ModelConfig) -> Array:
+    if cfg.embedding_multiplier is not None:
+        tok = tok * jnp.asarray(cfg.embedding_multiplier, tok.dtype)
+    return tok
 
 
 def _logits(params, cfg: ModelConfig, x: Array) -> Array:
@@ -269,6 +312,8 @@ def _logits(params, cfg: ModelConfig, x: Array) -> Array:
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
+        if cfg.logits_scaling is not None:
+            logits = logits / cfg.logits_scaling
         if cfg.logit_softcap > 0:
             c = cfg.logit_softcap
             logits = c * jnp.tanh(logits / c)
@@ -276,47 +321,46 @@ def _logits(params, cfg: ModelConfig, x: Array) -> Array:
 
 
 def forward_train(params, cfg: ModelConfig, batch: dict,
-                  rng: Optional[Array] = None) -> tuple[Array, Array]:
-    """Full forward. Returns (logits (B, L, V), aux_loss)."""
+                  rng: Optional[Array] = None) -> tuple[Array, dict]:
+    """Full forward. Returns (logits (B, L, V), aux): ``zero_aux``'s
+    tree summed over the layers."""
     x = _embed_inputs(params, cfg, batch)
     rng = rng if rng is not None else jax.random.PRNGKey(0)
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = zero_aux(cfg)
 
     def unit_body(x, xs):
         unit_params, uidx = xs
-        aux_u = jnp.zeros((), jnp.float32)
+        aux_u = zero_aux(cfg)
         for i, kind in enumerate(cfg.block_pattern):
             lk = jax.random.fold_in(rng, uidx * 16 + i)
             x, aux, _ = _apply_block(unit_params[f"b{i}"], x, cfg, kind,
                                      layer_key=lk, mode="train")
-            aux_u = aux_u + aux
+            aux_u = _add_aux(aux_u, aux)
         return x, aux_u
 
     if cfg.n_units > 0:
         body = unit_body
         policy = REMAT_POLICIES[cfg.remat]
         if policy is not None:
-            pol = (getattr(jax.checkpoint_policies, policy)
-                   if policy != "nothing_saveable"
-                   else jax.checkpoint_policies.nothing_saveable)
-            body = jax.checkpoint(unit_body, policy=pol,
+            body = jax.checkpoint(unit_body, policy=policy,
                                   prevent_cse=not cfg.scan_layers)
         if cfg.scan_layers:
             x, auxs = jax.lax.scan(
                 body, x, (params["units"], jnp.arange(cfg.n_units)))
-            aux_total = aux_total + jnp.sum(auxs)
+            aux_total = _add_aux(aux_total, jax.tree_util.tree_map(
+                lambda a: jnp.sum(a, axis=0), auxs))
         else:
             units = params["units"]
             for u in range(cfg.n_units):
                 up = jax.tree_util.tree_map(lambda a: a[u], units)
                 x, aux_u = body(x, (up, jnp.asarray(u)))
-                aux_total = aux_total + aux_u
+                aux_total = _add_aux(aux_total, aux_u)
     for i in range(cfg.n_rem):
         kind = cfg.block_pattern[i % len(cfg.block_pattern)]
         lk = jax.random.fold_in(rng, 10_000 + i)
         x, aux, _ = _apply_block(params["rem"][i], x, cfg, kind,
                                  layer_key=lk, mode="train")
-        aux_total = aux_total + aux
+        aux_total = _add_aux(aux_total, aux)
     return _logits(params, cfg, x), aux_total
 
 
@@ -363,7 +407,8 @@ def whitening_calibrate(params, cfg: ModelConfig, batch: dict,
     if cfg.attn.kind != "darkformer":
         return params
     taps = collect_qk(params, cfg, batch)
-    scale = cfg.head_dim ** -0.25
+    scale = (cfg.head_dim ** -0.25 if cfg.attention_multiplier is None
+             else cfg.attention_multiplier ** 0.5)
     new = jax.tree_util.tree_map(lambda a: a, params)
     plen = len(cfg.block_pattern)
     for name, (q, k) in taps.items():
@@ -396,7 +441,8 @@ def whitening_calibrate(params, cfg: ModelConfig, batch: dict,
 
 def loss_fn(params, cfg: ModelConfig, batch: dict,
             rng: Optional[Array] = None) -> tuple[Array, dict]:
-    logits, aux = forward_train(params, cfg, batch, rng)
+    logits, aux_tree = forward_train(params, cfg, batch, rng)
+    aux = aux_tree["loss"]
     with jax.named_scope(scopes.LOSS):
         labels = batch["labels"]
         if cfg.modality == "vlm":
@@ -413,8 +459,12 @@ def loss_fn(params, cfg: ModelConfig, batch: dict,
         zl = cfg.z_loss * jnp.sum(jnp.square(logz) * wmask) / denom
         loss = ce + zl + aux
         acc = jnp.sum((jnp.argmax(logits, -1) == labels) * wmask) / denom
-    return loss, {"loss": loss, "ce": ce, "z_loss": zl, "aux": aux,
-                  "accuracy": acc}
+    metrics = {"loss": loss, "ce": ce, "z_loss": zl, "aux": aux,
+               "accuracy": acc}
+    if cfg.moe:
+        metrics["moe_load"] = aux_tree["moe_load"]
+        metrics["moe_away"] = aux_tree["moe_away"]
+    return loss, metrics
 
 
 # ---------------------------------------------------------------------------
@@ -762,9 +812,7 @@ def decode_step(params, cfg: ModelConfig, token: Array, state: dict,
     one scanned layer body over the stacked layer axis.
     """
     pos = state["pos"]
-    x = params["embed"][token][:, None]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = _scale_embedding(params["embed"][token][:, None], cfg)
     x = x.astype(cfg.param_dtype)
     new_state: dict[str, Any] = {"pos": pos + 1}
     if proj is None and fused:

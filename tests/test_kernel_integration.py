@@ -44,14 +44,13 @@ def test_model_pallas_prefill_matches():
 @given(st.integers(0, 1000), st.integers(1, 4), st.sampled_from([1, 2, 4]))
 def test_moe_output_in_expert_span(seed, top_k, e_div):
     """Property: each token's MoE output is a convex combination (gates sum
-    to <=1 after capacity) of per-expert outputs — outputs stay bounded by
-    the max expert-output norm."""
+    to 1) of per-expert outputs — outputs stay bounded by the max
+    expert-output norm."""
     e = 4 * e_div
-    cfg = ll.MoEConfig(num_experts=e, top_k=top_k, d_ff=8,
-                       capacity_factor=4.0)
+    cfg = ll.MoEConfig(num_experts=e, top_k=top_k, d_ff=8)
     p = ll.moe_init(jax.random.PRNGKey(seed), 8, cfg)
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, 12, 8))
-    out, aux = ll.moe_apply(p, x, cfg)
+    out, aux, _ = ll.moe_apply(p, x, cfg)
     assert bool(jnp.all(jnp.isfinite(out)))
     # bound: ||out_t|| <= max_e ||f_e(x_t)||
     def expert_out(xt, ei):

@@ -1,4 +1,6 @@
 """Layer substrate: norms, rope, mlp, MoE."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,41 +57,139 @@ def test_mlp_kinds(kind):
     assert not bool(jnp.isnan(y).any())
 
 
-def test_moe_matches_dense_when_capacity_ample():
-    cfg = ll.MoEConfig(num_experts=8, top_k=2, d_ff=16,
-                       capacity_factor=4.0)
-    p = ll.moe_init(jax.random.PRNGKey(0), 12, cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 12))
-    out, aux = ll.moe_apply(p, x, cfg)
-    logits = x @ p["router"]
-    gv, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), 2)
-    gv = gv / gv.sum(-1, keepdims=True)
+def _moe_dense(p, x, cfg):
+    """The expert layer written plainly: each held expert's SwiGLU over
+    every token, weighted by the token's gate for it (0 where the token
+    was not routed there)."""
+    lo, hi = cfg.held_range
+    logits = jnp.einsum("bld,de->ble", x, p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(logits, cfg.top_k)
+    gates = jax.nn.softmax(top, axis=-1)
+    out = jnp.zeros_like(x)
+    for e in range(lo, hi):
+        w = jnp.sum(jnp.where(idx == e, gates, 0.0), axis=-1)
+        h = jax.nn.silu(x @ p["w_gate"][e - lo]) * (x @ p["w_up"][e - lo])
+        out = out + w[..., None] * (h @ p["w_out"][e - lo])
+    return out, idx
 
-    def per_tok(xt, it, gt):
-        o = jnp.zeros(12)
-        for kk in range(2):
-            e = it[kk]
-            h = jax.nn.silu(xt @ p["w_gate"][e]) * (xt @ p["w_up"][e])
-            o = o + gt[kk] * (h @ p["w_out"][e])
-        return o
 
-    expect = jax.vmap(jax.vmap(per_tok))(x, idx, gv)
+def _moe_case(skew, held=None, seed=0):
+    """A granite-shaped expert layer at small widths (40 experts, top 8);
+    with ``skew`` every token is routed to the same 8 experts."""
+    cfg = ll.MoEConfig(num_experts=40, top_k=8, d_ff=8, held=held)
+    d = 16
+    p = ll.moe_init(jax.random.PRNGKey(seed), d,
+                    dataclasses.replace(cfg, held=None))
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, 24, d))
+    if skew:
+        hot = jnp.array([3, 7, 11, 19, 23, 29, 31, 37])
+        col = jnp.full((40,), -1.0).at[hot].set(1.0 + 0.1 * jnp.arange(8))
+        p["router"] = jnp.broadcast_to(col, (d, 40)) * 1.0
+        x = jnp.abs(x)
+    if held is not None:
+        lo, hi = held
+        p = dict(p, **{k: p[k][lo:hi] for k in ("w_gate", "w_up",
+                                                  "w_out")})
+    return cfg, p, x
+
+
+@pytest.mark.parametrize("skew", [False, True], ids=["spread", "skewed"])
+def test_moe_matches_dense_when_capacity_ample(skew):
+    """Dropless routing equals the plain dense-over-experts layer, also
+    when every token goes to the same 8 of 40 experts, and the load
+    counter equals what the router assigned."""
+    cfg, p, x = _moe_case(skew)
+    out, aux, counters = ll.moe_apply(p, x, cfg)
+    expect, idx = _moe_dense(p, x, cfg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-5)
     assert float(aux) > 0
+    np.testing.assert_array_equal(
+        np.asarray(counters["load"]),
+        np.bincount(np.asarray(idx).ravel(), minlength=40))
+    assert int(counters["away"]) == 0
+    if skew:
+        assert int(jnp.sum(counters["load"] > 0)) == 8
 
 
-def test_moe_capacity_drops_tokens():
-    """With tiny capacity, some tokens must be dropped (output ~ 0 for
-    them), and outputs stay finite."""
-    cfg = ll.MoEConfig(num_experts=4, top_k=1, d_ff=8,
-                       capacity_factor=0.25)
-    p = ll.moe_init(jax.random.PRNGKey(0), 8, cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 8))
-    out, _ = ll.moe_apply(p, x, cfg)
-    assert not bool(jnp.isnan(out).any())
-    row_norms = jnp.linalg.norm(out[0], axis=-1)
-    assert float(jnp.min(row_norms)) < 1e-6      # dropped tokens exist
+@pytest.mark.parametrize("skew", [False, True], ids=["spread", "skewed"])
+def test_moe_expert_halves_sum_to_the_whole(skew):
+    """Two devices holding experts [0, 20) and [20, 40) give outputs that
+    sum to the whole layer's, and their counters split its pairs."""
+    cfg, p, x = _moe_case(skew)
+    whole, _, cw = ll.moe_apply(p, x, cfg)
+    parts = []
+    for held in ((0, 20), (20, 40)):
+        cfg_h, p_h, _ = _moe_case(skew, held)
+        parts.append(ll.moe_apply(p_h, x, cfg_h))
+    np.testing.assert_allclose(np.asarray(parts[0][0] + parts[1][0]),
+                               np.asarray(whole), atol=1e-5)
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(c["load"]) for _, _, c in parts]),
+        np.asarray(cw["load"]))
+    n = x.shape[0] * x.shape[1] * cfg.top_k
+    for _, _, c in parts:
+        assert int(jnp.sum(c["load"])) + int(c["away"]) == n
+
+
+@pytest.mark.parametrize("skew", [False, True], ids=["spread", "skewed"])
+def test_moe_gradients_match_dense(skew):
+    """Gradients to the router, the experts and the input equal those of
+    the dense-over-experts layer."""
+    cfg, p, x = _moe_case(skew)
+
+    def loss(f):
+        def inner(pp, xx):
+            y = f(pp, xx)
+            return jnp.sum(y * jnp.sin(jnp.arange(y.size).reshape(y.shape)))
+        return jax.grad(inner, argnums=(0, 1))(p, x)
+    got = loss(lambda pp, xx: ll.moe_apply(pp, xx, cfg)[0])
+    want = loss(lambda pp, xx: _moe_dense(pp, xx, cfg)[0])
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4)
+
+
+def test_moe_rows_no_group_covers_never_reach_the_result(monkeypatch):
+    """The grouped matmuls leave the rows of pairs routed away undefined
+    (on a TPU they hold whatever was there): with those rows NaN, the
+    output and every gradient of a half-held layer are still the
+    layer's own."""
+    cfg, p, x = _moe_case(False, held=(0, 20))
+    orig = jax.lax.ragged_dot
+
+    def nan_rows(a, group_sizes):
+        rows = jnp.arange(a.shape[0])[:, None] >= jnp.sum(group_sizes)
+        return jnp.where(rows, jnp.nan, a)
+
+    @jax.custom_vjp
+    def undefined_rows(lhs, rhs, group_sizes):
+        return nan_rows(orig(lhs, rhs, group_sizes), group_sizes)
+
+    def fwd(lhs, rhs, group_sizes):
+        out, vjp = jax.vjp(lambda a, b: orig(a, b, group_sizes), lhs, rhs)
+        return nan_rows(out, group_sizes), (vjp, group_sizes)
+
+    def bwd(res, ct):
+        vjp, group_sizes = res
+        d_lhs, d_rhs = vjp(ct)
+        return nan_rows(d_lhs, group_sizes), d_rhs, None
+
+    undefined_rows.defvjp(fwd, bwd)
+
+    def run():
+        return jax.value_and_grad(
+            lambda pp, xx: jnp.sum(ll.moe_apply(pp, xx, cfg)[0] ** 2),
+            argnums=(0, 1))(p, x)
+    want = run()
+    monkeypatch.setattr(jax.lax, "ragged_dot", undefined_rows)
+    got = run()
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
 def test_moe_grads_flow_to_router_and_experts():

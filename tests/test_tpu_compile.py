@@ -98,21 +98,23 @@ def test_linear_attention_causal_compiles(one_chip):
 
 
 # the training mix's Pallas pair at the widths that run it: smollm-135m
-# (3 heads a group, d_head 64) and granite-8b (4 heads a group, d_head
-# 128), m = 256, 2048-token rows; both kernels of the backward as well
-@pytest.mark.parametrize("hg,dv", [(HG, D), (4, 128)],
-                         ids=["smollm", "granite"])
-def test_prf_mix_pair_compiles(one_chip, monkeypatch, hg, dv):
+# (3 groups of 3 heads, d_head 64, 2048-token rows), granite-8b (4 heads
+# a group, d_head 128) and granite-moe-3b-a800m (8 groups of 3 heads,
+# d_head 64, 4096-token rows), m = 256; both kernels of the backward too
+@pytest.mark.parametrize("g,hg,dv,l", [(G, HG, D, 2048), (G, 4, 128, 2048),
+                                       (8, 3, 64, 4096)],
+                         ids=["smollm", "granite", "granitemoe"])
+def test_prf_mix_pair_compiles(one_chip, monkeypatch, g, hg, dv, l):
     monkeypatch.setattr(ops, "_use_interpret", lambda: False)
 
-    def f(q, k, v, g):
+    def f(q, k, v, ct):
         out, vjp = jax.vjp(lambda *a: ops.linear_attention_causal(
             *a, eps=1e-30), q, k, v)
-        return out, vjp(g)
-    n, l = 2, 2048
-    hlo = _compile(f, one_chip, ((n, G, hg, l, M), F32),
-                   ((n, G, 1, l, M), F32), ((n, G, 1, l, dv), BF16),
-                   ((n, G, hg, l, dv), BF16)).as_text()
+        return out, vjp(ct)
+    n = 2
+    hlo = _compile(f, one_chip, ((n, g, hg, l, M), F32),
+                   ((n, g, 1, l, M), F32), ((n, g, 1, l, dv), BF16),
+                   ((n, g, hg, l, dv), BF16)).as_text()
     assert {name for name, _ in _kernel_scopes(hlo)} == set(KERNELS)
 
 
@@ -134,14 +136,8 @@ def _kernel_scopes(hlo: str) -> set:
     return found
 
 
-def test_train_step_takes_the_prf_mix_kernels(one_chip, monkeypatch):
-    """The smollm-135m training step (value_and_grad through the remat'd
-    layer scan, two layers at full width, 2048-token rows) runs its
-    causal PRF mix in the Pallas pair, forward and backward, under the
-    ``prf_mix`` scope, and holds no (L, L) array."""
-    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
-    cfg = dataclasses.replace(cfgs.get_config("smollm-135m"), n_layers=2)
-    l = 2048
+def _train_step_hlo(cfg, one_chip, rows, l):
+    """The compiled text of ``cfg``'s training step for one v5e chip."""
     opt_cfg = AdamWConfig()
     step = steps_lib.make_train_step(cfg, opt_cfg, lambda s: 1e-4)
 
@@ -152,14 +148,48 @@ def test_train_step_takes_the_prf_mix_kernels(one_chip, monkeypatch):
     params = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0),
                                                    cfg))
     opt = jax.eval_shape(lambda: adamw_init(params, opt_cfg))
-    batch = {k: jax.ShapeDtypeStruct((1, l), jnp.int32, sharding=one_chip)
+    batch = {k: jax.ShapeDtypeStruct((rows, l), jnp.int32,
+                                     sharding=one_chip)
              for k in ("tokens", "labels")}
-    hlo = jax.jit(step).lower(
+    return jax.jit(step).lower(
         place(params), place(opt), batch,
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     ).compile().as_text()
+
+
+def test_train_step_takes_the_prf_mix_kernels(one_chip, monkeypatch):
+    """The smollm-135m training step (value_and_grad through the remat'd
+    layer scan, two layers at full width, 2048-token rows) runs its
+    causal PRF mix in the Pallas pair, forward and backward, under the
+    ``prf_mix`` scope, and holds no (L, L) array."""
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    cfg = dataclasses.replace(cfgs.get_config("smollm-135m"), n_layers=2)
+    l = 2048
+    hlo = _train_step_hlo(cfg, one_chip, 1, l)
     assert _kernel_scopes(hlo) == {(name, "prf_mix") for name in KERNELS}
     assert not re.search(rf"\[(\d+,)*{l},{l}\]", hlo)
+
+
+def test_granitemoe_train_step_compiles(one_chip, monkeypatch):
+    """The granite-moe-3b-a800m training step at the benchmark cell's
+    cut (20 of 40 experts held, a 24576-row vocabulary slice, every
+    width as published; three of its layers, the scanned body is the
+    same)
+    on 2 rows of 4096 tokens compiles for a v5e: the PRF mix takes the
+    Pallas pair at 8 groups of 3 heads, the experts run as grouped
+    matmuls, and it holds no (L, L) array and no per-expert capacity
+    buffer (B, E, C, d)."""
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    cfg = cfgs.get_config("granite-moe-3b-a800m", n_layers=3, vocab=24576,
+                          moe={"held": [0, 20]})
+    assert cfg.moe.n_held == 20 and cfg.moe.num_experts == 40
+    l = 4096
+    hlo = _train_step_hlo(cfg, one_chip, 2, l)
+    assert _kernel_scopes(hlo) == {(name, "prf_mix") for name in KERNELS}
+    assert "bf16[2,8,3,4096,256]" in hlo      # q features, 8 groups of 3
+    assert "ragged-dot" in hlo
+    assert not re.search(rf"\[(\d+,)*{l},{l}\]", hlo)
+    assert not re.search(r"\[2,(20|40),\d+,1536\]", hlo)
 
 
 def test_linear_attention_carry_compiles(one_chip):
