@@ -154,23 +154,33 @@ def moe_init(key, d_model: int, cfg: MoEConfig, dtype=jnp.float32) -> dict:
     }
 
 
+def _zero_rows(x, keep):
+    """``x`` with 0 in the rows where ``keep`` is False (None: none)."""
+    return x if keep is None else jnp.where(keep[:, None], x, 0)
+
+
 @jax.custom_vjp
-def _permute_rows(x, order, inv):
-    """``x[order]`` for a permutation ``order`` with inverse ``inv``;
-    the backward pass is the gather ``ct[inv]``, not a scatter-add."""
-    return x[order]
+def _move_rows(src, rows, keep, back, ct_keep):
+    """``src[rows]``, 0 in the rows where ``keep`` is False. The backward
+    pass is the reverse move ``ct[back]``, after 0 in the rows where
+    ``ct_keep`` is False (whose cotangent is undefined), summed over each
+    source row's ``len(rows) / len(src)`` copies (consecutive rows of
+    ``back``): a gather, not a scatter-add."""
+    return _zero_rows(src[rows], keep)
 
 
-def _permute_rows_fwd(x, order, inv):
-    return x[order], (order, inv)
+def _move_rows_fwd(src, rows, keep, back, ct_keep):
+    return _zero_rows(src[rows], keep), (back, ct_keep, src.shape[0])
 
 
-def _permute_rows_bwd(res, ct):
-    order, inv = res
-    return ct[inv], None, None
+def _move_rows_bwd(res, ct):
+    back, ct_keep, n_src = res
+    g = _zero_rows(ct, ct_keep)[back]
+    return (g.reshape(n_src, -1, g.shape[-1]).sum(1),
+            None, None, None, None)
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+_move_rows.defvjp(_move_rows_fwd, _move_rows_bwd)
 
 
 def moe_apply(params: dict, x: Array, cfg: MoEConfig
@@ -184,8 +194,10 @@ def moe_apply(params: dict, x: Array, cfg: MoEConfig
     experts first, and run through grouped matmuls (``lax.ragged_dot``)
     over the held experts' group sizes, so every such pair is computed,
     whatever the skew. Pairs routed to experts held elsewhere add
-    nothing here: their rows, which no group covers, are masked on the
-    way in and out (the grouped matmuls leave such rows undefined).
+    nothing here: their rows, which no group covers, come out of the
+    grouped matmuls undefined, forward and backward, and are masked
+    where they leave them (the combine's forward, the dispatch's
+    backward); on the way in the grouped matmuls ignore them.
     ``counters``: ``load`` (held,) int32, the pairs each held expert
     took; ``away`` () int32, the pairs routed to experts not held.
     """
@@ -213,9 +225,11 @@ def moe_apply(params: dict, x: Array, cfg: MoEConfig
         load = jnp.sum(jax.nn.one_hot(key, n_held, dtype=jnp.int32), 0)
         order, inv, load = (checkpoint_name(a, MOE_SAVED)
                             for a in (order, inv, load))
-        held_rows = (jnp.arange(t * k) < jnp.sum(load))[:, None]
-        xs = checkpoint_name(jnp.where(held_rows, _permute_rows(
-            jnp.repeat(xt, k, axis=0), order, inv), 0), MOE_SAVED)
+        n_in = jnp.sum(load)
+        # sorted row i is token order[i] // k: read from the token rows
+        xs = checkpoint_name(_move_rows(xt, order // k, None, inv,
+                                        jnp.arange(t * k) < n_in),
+                             MOE_SAVED)
     with jax.named_scope(scopes.MOE_EXPERTS):
         hg = checkpoint_name(jax.lax.ragged_dot(xs, params["w_gate"], load),
                              MOE_SAVED)
@@ -227,9 +241,8 @@ def moe_apply(params: dict, x: Array, cfg: MoEConfig
                              MOE_SAVED)
     with jax.named_scope(scopes.MOE_COMBINE):
         # back to each token's k choices, then the gated sum
-        ys = jnp.where(held_rows, ys, 0)
-        y = checkpoint_name(_permute_rows(ys, inv, order), MOE_SAVED
-                            ).reshape(t, k, d)
+        y = checkpoint_name(_move_rows(ys, inv, inv < n_in, order, None),
+                            MOE_SAVED).reshape(t, k, d)
         out = jnp.einsum("tkd,tk->td", y, gates.astype(y.dtype))
-    counters = {"load": load, "away": t * k - jnp.sum(load)}
+    counters = {"load": load, "away": t * k - n_in}
     return out.reshape(b, l, d), aux, counters

@@ -1,5 +1,6 @@
 """Layer substrate: norms, rope, mlp, MoE."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -190,6 +191,49 @@ def test_moe_rows_no_group_covers_never_reach_the_result(monkeypatch):
                     jax.tree_util.tree_leaves(want)):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+def _routing(seed, t=12, k=4, n_held=5):
+    """A sort of t * k pairs by expert, the away ones (expert n_held)
+    last: ``order``, its inverse ``inv``, and the pairs held."""
+    key = jax.random.randint(jax.random.PRNGKey(seed), (t * k,), 0,
+                             n_held + 1)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    return order, inv, jnp.sum(key < n_held)
+
+
+# the expert layer's two row moves against plain indexing and autodiff,
+# on whole numbers (so any order of the k-sum is exact), with NaN in
+# every row the grouped matmuls leave undefined
+@pytest.mark.parametrize("move", ["dispatch", "combine"])
+def test_moe_row_moves_match_indexing(move):
+    t, k, d = 12, 4, 8
+    order, inv, n_in = _routing(3, t, k)
+    sorted_in = (jnp.arange(t * k) < n_in)[:, None]
+    ints = functools.partial(jax.random.randint, minval=-8, maxval=8)
+    if move == "dispatch":       # token rows -> expert order
+        src = ints(jax.random.PRNGKey(1), (t, d)).astype(jnp.float32)
+        args = (order // k, None, inv, sorted_in[:, 0])
+        ref = lambda s: s[order // k]
+        ct = jnp.where(sorted_in, ints(jax.random.PRNGKey(2), (t * k, d)),
+                       jnp.nan)
+        ref_ct, rows = jnp.where(sorted_in, ct, 0), slice(None)
+    else:                        # expert order -> each token's k choices
+        src = jnp.where(sorted_in, ints(jax.random.PRNGKey(1), (t * k, d)),
+                        jnp.nan)
+        args = (inv, inv < n_in, order, None)
+        ref = lambda s: jnp.where((inv < n_in)[:, None],
+                                  jnp.where(sorted_in, s, 0)[inv], 0)
+        ct = ints(jax.random.PRNGKey(2), (t * k, d)).astype(jnp.float32)
+        ref_ct, rows = ct, slice(0, int(n_in))   # the rest: ignored
+    out, vjp = jax.vjp(lambda s: ll._move_rows(s, *args), src)
+    want, ref_vjp = jax.vjp(ref, src)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    got, = vjp(ct)
+    assert bool(jnp.all(jnp.isfinite(got[rows])))
+    np.testing.assert_array_equal(np.asarray(got[rows]),
+                                  np.asarray(ref_vjp(ref_ct)[0][rows]))
 
 
 def test_moe_grads_flow_to_router_and_experts():

@@ -170,6 +170,29 @@ def test_train_step_takes_the_prf_mix_kernels(one_chip, monkeypatch):
     assert not re.search(rf"\[(\d+,)*{l},{l}\]", hlo)
 
 
+def _routing_row_passes(hlo: str, rows: str) -> list:
+    """(op, fused ops) of each top-level op of ``hlo`` that writes the
+    expert layer's (token, choice) rows, shape ``rows``, under the
+    ``moe_dispatch`` or ``moe_combine`` scope."""
+    comps = dict(re.findall(r"\n%?([\w.\-]+) [^\n]*\{\n(.*?)\n\}", hlo,
+                            re.S))
+    found = []
+    for name, body in comps.items():
+        if "fused" in name:
+            continue
+        for line in body.split("\n"):
+            m = re.match(rf"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[{rows}\]\S* "
+                         r"(fusion|custom-call|reduce-precision|copy)\(", line)
+            if m and re.search(r'op_name="[^"]*/moe_(?:dispatch|combine)/',
+                               line):
+                calls = re.search(r"calls=%?([\w.\-]+)", line)
+                fused = set(re.findall(
+                    r"= \S+ (gather|select|broadcast|reduce-precision|add|"
+                    r"multiply)\(", comps[calls.group(1)])) if calls else None
+                found.append((m.group(1), fused or {m.group(2)}))
+    return found
+
+
 def test_granitemoe_train_step_compiles(one_chip, monkeypatch):
     """The granite-moe-3b-a800m training step at the benchmark cell's
     cut (20 of 40 experts held, a 24576-row vocabulary slice, every
@@ -178,7 +201,11 @@ def test_granitemoe_train_step_compiles(one_chip, monkeypatch):
     on 2 rows of 4096 tokens compiles for a v5e: the PRF mix takes the
     Pallas pair at 8 groups of 3 heads, the experts run as grouped
     matmuls, and it holds no (L, L) array and no per-expert capacity
-    buffer (B, E, C, d)."""
+    buffer (B, E, C, d). The routing writes its 65536 rows of 1536 in
+    seven passes a layer: four gathers (dispatch and combine, forward
+    and backward), the dispatch's reduce-precision, the backward's
+    gated product and its masked add; no broadcast of the token rows to
+    their 8 choices, and no mask of its own."""
     monkeypatch.setattr(ops, "_use_interpret", lambda: False)
     cfg = cfgs.get_config("granite-moe-3b-a800m", n_layers=3, vocab=24576,
                           moe={"held": [0, 20]})
@@ -190,6 +217,11 @@ def test_granitemoe_train_step_compiles(one_chip, monkeypatch):
     assert "ragged-dot" in hlo
     assert not re.search(rf"\[(\d+,)*{l},{l}\]", hlo)
     assert not re.search(r"\[2,(20|40),\d+,1536\]", hlo)
+    passes = _routing_row_passes(hlo, "65536,1536")
+    assert len(passes) == 7, passes
+    assert sum("gather" in ops_ for _, ops_ in passes) == 4, passes
+    assert not any(ops_ <= {"select", "broadcast"} for _, ops_ in passes)
+    assert not _routing_row_passes(hlo, "8192,8,1536")
 
 
 def test_linear_attention_carry_compiles(one_chip):
